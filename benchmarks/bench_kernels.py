@@ -57,16 +57,16 @@ def _lane_step(params, opt, batch, hp):
             {"loss": loss})
 
 
-def _pool_step(interpret: bool):
+def _pool_step(impl: str):
     """The pool-level mask-aware twin of ``_lane_step`` for "kernel"
     mode: the two matmuls go through the lane-masked packed kernels."""
     def step(params, opt, batch, hp, active):
         pred = ops.packed_matmul(batch["x"], params["w"], active=active,
-                                 interpret=interpret)
+                                 impl=impl)
         err = pred - batch["y"]
         xt = jnp.swapaxes(batch["x"], -1, -2)
         grad = ops.packed_matmul(xt, err, active=active,
-                                 interpret=interpret) / batch["x"].shape[-2]
+                                 impl=impl) / batch["x"].shape[-2]
         loss = jnp.mean(err * err, axis=(-1, -2))
         return ({"w": params["w"] - hp.reshape(-1, 1, 1) * grad},
                 {"m": opt["m"] * 0.9 + loss * 0.1},
@@ -102,7 +102,7 @@ def check_masked_modes() -> dict:
     where = packing.masked_pool_step(_lane_step, mode="where", donate=False)
     compact = packing.masked_pool_step(_lane_step, mode="compact",
                                        donate=False)
-    kernel = packing.masked_pool_step(_pool_step(interpret=True),
+    kernel = packing.masked_pool_step(_pool_step("pallas_interpret"),
                                       mode="kernel", donate=False)
     checked = 0
     for occ in (0.25, 0.5, 0.75, 1.0):

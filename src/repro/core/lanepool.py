@@ -59,8 +59,18 @@ from repro.core import packing
 class PoolStepError(RuntimeError):
     """The compiled masked step failed — a POOL-WIDE event (a packed
     program's OOM kills all lanes at once). Raised chained to the original
-    exception so callers can distinguish a pool failure (back off, rebuild
-    smaller) from a bug in their own callbacks (which propagates raw)."""
+    exception so callers can distinguish a pool failure from a bug in
+    their own callbacks (which propagates raw). Only ``oom`` failures
+    warrant backing off to a smaller pool; any other cause (a refused
+    kernel, a shape error, a device fault) is a real failure."""
+
+    @property
+    def oom(self) -> bool:
+        """True when the device ran out of memory (XLA's
+        RESOURCE_EXHAUSTED status)."""
+        cause = self.__cause__
+        return (isinstance(cause, jax.errors.JaxRuntimeError)
+                and "RESOURCE_EXHAUSTED" in str(cause))
 
 
 @dataclasses.dataclass

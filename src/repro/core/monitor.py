@@ -47,8 +47,6 @@ class StaticProfile:
 def profile_compiled(compiled) -> StaticProfile:
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax <= 0.4.x wraps the dict
-        ca = ca[0] if ca else {}
     return StaticProfile(
         argument_bytes=int(ma.argument_size_in_bytes),
         temp_bytes=int(ma.temp_size_in_bytes),
@@ -69,11 +67,20 @@ def profile_fn(fn, *example_args, **kw) -> StaticProfile:
 
 def live_device_bytes() -> int:
     """Sum of live committed jax arrays (the 'GPU memory used' column)."""
-    try:
-        arrs = jax.live_arrays()
-    except Exception:
-        return 0
-    return int(sum(a.nbytes for a in arrs if hasattr(a, "nbytes")))
+    return int(sum(a.nbytes for a in jax.live_arrays()))
+
+
+def device_hbm_budget(device=None) -> int:
+    """Bytes still free on ``device`` (default: the first device): the
+    allocator's ``bytes_limit`` less its ``bytes_in_use``. The budget
+    auto_nppn packs against on a real chip; a backend that reports no
+    memory statistics (the CPU) is an error, not a guess."""
+    device = device or jax.devices()[0]
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        raise RuntimeError(f"{device.platform} device {device} reports no "
+                           f"memory_stats; name an hbm_budget explicitly")
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
 
 
 @dataclasses.dataclass

@@ -16,8 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-import repro.compat  # noqa: F401  (jax version shims)
-
 
 def allgather_matmul(x: jax.Array, w_shard: jax.Array, axis: str) -> jax.Array:
     """Inside shard_map. x (T, K) replicated over ``axis``; w_shard

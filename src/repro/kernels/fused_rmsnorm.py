@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                 # (rows, d)
@@ -51,7 +49,7 @@ def fused_rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, w)
@@ -94,15 +92,18 @@ def packed_rmsnorm(x: jax.Array, w: jax.Array, *,
     else:
         act = jnp.asarray(active, jnp.int32).reshape(J)
     grid = (J, (rows + pad) // br)
+    # w as (J, 1, d): a (1, 1, d) block spans the array's last two dims,
+    # which the TPU lowering requires of a block that is not (8, 128)-tiled
+    w = w.reshape(J, 1, d)
     out = pl.pallas_call(
         functools.partial(_packed_rmsnorm_kernel, eps=eps),
         grid=grid,
         in_specs=[pl.BlockSpec((1, br, d), lambda j, i: (j, i, 0)),
-                  pl.BlockSpec((1, d), lambda j, i: (j, 0)),
+                  pl.BlockSpec((1, 1, d), lambda j, i: (j, 0, 0)),
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((1, br, d), lambda j, i: (j, i, 0)),
         out_shape=jax.ShapeDtypeStruct((J, rows + pad, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, w, act)

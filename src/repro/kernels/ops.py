@@ -1,9 +1,12 @@
 """Jit'd dispatch wrappers around the Pallas kernels.
 
-``impl`` resolution: "pallas" on TPU, "xla" elsewhere; tests force
-"pallas_interpret". The flash-attention wrapper carries a custom_vjp whose
-backward is recompute through the memory-efficient jnp path, so the kernels
-are usable inside train_step.
+``impl`` is chosen in one place, ``resolve_impl``: an explicit
+"pallas", "pallas_interpret" or "xla" is taken as given (an explicit
+"pallas" off-TPU fails to lower instead of quietly running XLA), and
+``None`` means the platform's own path — the Pallas kernels on a TPU,
+XLA elsewhere. Tests pass "pallas_interpret". The flash-attention
+wrapper carries a custom_vjp whose backward is recompute through the
+memory-efficient jnp path, so the kernels are usable inside train_step.
 
 Lane masking: every packed/lane-batched entrypoint here —
 ``packed_matmul``, ``packed_norm``, ``flash_attention``, ``ssd`` —
@@ -31,8 +34,18 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _use_pallas(interpret: bool) -> bool:
-    return interpret or jax.default_backend() == "tpu"
+IMPLS = ("pallas", "pallas_interpret", "xla")
+
+
+def resolve_impl(impl: Optional[str] = None) -> str:
+    """The kernel path for ``impl``: an explicit choice as given, and
+    for ``None`` the Pallas kernels on a TPU backend, XLA elsewhere."""
+    if impl is None:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl not in IMPLS:
+        raise ValueError(f"unknown kernel impl {impl!r}; expected one of "
+                         f"{IMPLS} or None")
+    return impl
 
 
 # ---------------------------------------------------------------------------
@@ -40,21 +53,20 @@ def _use_pallas(interpret: bool) -> bool:
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_core(q, k, v, causal: bool = True, window: int = 0,
-                          interpret: bool = False):
-    if _use_pallas(interpret):
+def _flash_attention_core(q, k, v, causal: bool, window: int, impl: str):
+    if impl != "xla":
         from repro.kernels.flash_attention import flash_attention_fwd
         return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                   interpret=interpret)
+                                   interpret=impl == "pallas_interpret")
     from repro.models.attention import sdpa_chunked
     return sdpa_chunked(q, k, v, causal=causal, window=window)
 
 
-def _fa_fwd(q, k, v, causal, window, interpret):
-    return _flash_attention_core(q, k, v, causal, window, interpret), (q, k, v)
+def _fa_fwd(q, k, v, causal, window, impl):
+    return _flash_attention_core(q, k, v, causal, window, impl), (q, k, v)
 
 
-def _fa_bwd(causal, window, interpret, res, g):
+def _fa_bwd(causal, window, impl, res, g):
     q, k, v = res
     from repro.models.attention import sdpa_chunked
     _, vjp = jax.vjp(
@@ -82,24 +94,25 @@ def _mask_lanes(active, *arrays):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_attention_masked_core(q, k, v, active, causal: bool = True,
-                                 window: int = 0, interpret: bool = False):
-    if _use_pallas(interpret):
+def _flash_attention_masked_core(q, k, v, active, causal: bool,
+                                 window: int, impl: str):
+    if impl != "xla":
         from repro.kernels.flash_attention import flash_attention_fwd
         return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                   active=active, interpret=interpret)
+                                   active=active,
+                                   interpret=impl == "pallas_interpret")
     from repro.models.attention import sdpa_chunked
     return _mask_lanes(active,
                        sdpa_chunked(q, k, v, causal=causal, window=window))
 
 
-def _fam_fwd(q, k, v, active, causal, window, interpret):
+def _fam_fwd(q, k, v, active, causal, window, impl):
     out = _flash_attention_masked_core(q, k, v, active, causal, window,
-                                       interpret)
+                                       impl)
     return out, (q, k, v, active)
 
 
-def _fam_bwd(causal, window, interpret, res, g):
+def _fam_bwd(causal, window, impl, res, g):
     q, k, v, active = res
     from repro.models.attention import sdpa_chunked
     _, vjp = jax.vjp(
@@ -116,7 +129,7 @@ _flash_attention_masked_core.defvjp(_fam_fwd, _fam_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
-                    interpret: bool = False, *, active=None):
+                    impl: Optional[str] = None, *, active=None):
     """Flash attention with the lane-mask contract of DESIGN.md §12:
     ``active`` (bool/int (B,), optional) treats the batch dim as lane
     axis — inactive lanes' outputs are exact zeros, active lanes are
@@ -126,29 +139,30 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
     in-kernel (flash_attention._fwd_masked_kernel); the XLA fallback
     where-zeroes outside the dots. Both run under a custom_vjp whose
     backward is recompute through sdpa_chunked."""
+    impl = resolve_impl(impl)
     if active is None:
-        return _flash_attention_core(q, k, v, causal, window, interpret)
+        return _flash_attention_core(q, k, v, causal, window, impl)
     act = jnp.asarray(active, jnp.int32)
-    return _flash_attention_masked_core(q, k, v, act, causal, window,
-                                        interpret)
+    return _flash_attention_masked_core(q, k, v, act, causal, window, impl)
 
 
 # ---------------------------------------------------------------------------
 # SSD scan
 # ---------------------------------------------------------------------------
 
-def ssd(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False,
+def ssd(x, dt, A, B, C, *, chunk: int = 128, impl: Optional[str] = None,
         active=None):
-    """Dispatch to kernel on TPU / interpret, else chunked jnp.
+    """Dispatch to the kernel or the chunked jnp path (``resolve_impl``).
 
     ``active`` (bool/int (b,), optional): per-lane predicate over the
     batch dim — inactive lanes' y AND final state are exact zeros
     (where-zero applied to both outputs), active lanes bit-identical;
     ``active=None`` leaves the program untouched."""
-    if _use_pallas(interpret):
+    impl = resolve_impl(impl)
+    if impl != "xla":
         from repro.kernels.ssd_scan import ssd_scan
         y, state = ssd_scan(x, dt, A, B, C, chunk=chunk,
-                            interpret=interpret)
+                            interpret=impl == "pallas_interpret")
     else:
         from repro.models.ssm import ssd_chunked
         y, state = ssd_chunked(x, dt, A, B, C, chunk=chunk)
@@ -161,13 +175,15 @@ def ssd(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False,
 # packed (multi-job) GEMM
 # ---------------------------------------------------------------------------
 
-def packed_matmul(x, w, *, active=None, interpret: bool = False):
+def packed_matmul(x, w, *, active=None, impl: Optional[str] = None):
     """x (J,M,K) @ w (J,K,N) per job. ``active`` (bool/int (J,), optional)
     zeroes inactive lanes — fused into the kernel on the Pallas path,
     where-masked on the XLA fallback."""
-    if _use_pallas(interpret):
+    impl = resolve_impl(impl)
+    if impl != "xla":
         from repro.kernels.packed_gemm import packed_gemm
-        return packed_gemm(x, w, active=active, interpret=interpret)
+        return packed_gemm(x, w, active=active,
+                           interpret=impl == "pallas_interpret")
     from repro.kernels.ref import packed_gemm_ref
     out = packed_gemm_ref(x, w)
     if active is not None:
@@ -177,13 +193,14 @@ def packed_matmul(x, w, *, active=None, interpret: bool = False):
 
 
 def packed_norm(x, w, *, active=None, eps: float = 1e-5,
-                interpret: bool = False):
+                impl: Optional[str] = None):
     """Lane-batched RMSNorm: x (J,rows,d), per-lane weights w (J,d).
     Same ``active`` contract as packed_matmul (inactive lanes -> zeros)."""
-    if _use_pallas(interpret):
+    impl = resolve_impl(impl)
+    if impl != "xla":
         from repro.kernels.fused_rmsnorm import packed_rmsnorm
         return packed_rmsnorm(x, w, active=active, eps=eps,
-                              interpret=interpret)
+                              interpret=impl == "pallas_interpret")
     from repro.models.layers import rms_norm
     out = jax.vmap(lambda xi, wi: rms_norm(xi, wi, eps))(x, w)
     if active is not None:

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _pg_kernel(x_ref, w_ref, o_ref, acc_scr):
     ki = pl.program_id(3)
@@ -104,7 +102,7 @@ def packed_gemm(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((1, bm, bn), lambda j, i, n, k: (j, i, n)),
         out_shape=jax.ShapeDtypeStruct((J, Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -32,7 +32,7 @@ from repro.launch.serve import make_prefill, make_serve_step
 from repro.launch.train import make_train_step
 from repro.models.model import Model
 from repro.models.transformer import ParallelCtx
-from repro.roofline.analysis import analyze_compiled
+from repro.roofline.analysis import HW, analyze_compiled
 
 # zamba2's shared attention runs a 4096 sliding window at 500k (DESIGN.md)
 LONG_WINDOW = {"zamba2-7b": 4096}
@@ -143,7 +143,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         n_params = base_cfg.active_param_count()   # 6·N_active·D for MoE
         rep = analyze_compiled(
             compiled, arch=arch, shape=shape_name, mesh_name=mname,
-            chips=mesh.size, n_params=n_params, n_tokens=n_tokens, kind=kind)
+            chips=mesh.size, n_params=n_params, n_tokens=n_tokens, kind=kind,
+            hw=HW.for_arch("v5e"))
         from repro.roofline.analysis import attn_kernel_io_bytes
         rep.kernel_io_bytes = attn_kernel_io_bytes(
             model.cfg, SHAPES_BY_NAME[shape_name].tokens

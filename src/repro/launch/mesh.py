@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import jax
 
-import repro.compat  # noqa: F401  (backfills AxisType / axis_types on old jax)
 from jax.sharding import AxisType
 
 

@@ -11,9 +11,10 @@ while work remains queued.
 
 Checkpoints are per task (``{checkpoint_dir}/task_{id}``), written when a
 lane detaches and every ``FaultPolicy.checkpoint_every`` steps mid-flight;
-a re-run restores each task's saved state and skips the finished steps. OOM-backoff halves the pool capacity and re-enqueues the
-unfinished tasks (in-flight progress of the failed pool is discarded, as a
-packed-program OOM kills all lanes at once).
+a re-run restores each task's saved state and skips the finished steps.
+OOM-backoff halves the pool capacity and re-enqueues the unfinished tasks
+(in-flight progress of the failed pool is discarded, as a packed-program
+OOM kills all lanes at once); any other pool-step failure propagates.
 """
 from __future__ import annotations
 
@@ -148,13 +149,17 @@ def run_sweep(model: Model, tasks: Sequence[SweepTask], *,
         return jax.vmap(step_fn)
 
     def example_args(k):
-        keys = jax.random.split(jax.random.PRNGKey(0), k)
-        p = jax.vmap(model.init)(keys)
-        o = jax.vmap(opt.init)(p)
+        """Shapes only: a probe compiles, it never allocates k lanes."""
+        def lanes():
+            keys = jax.random.split(jax.random.PRNGKey(0), k)
+            p = jax.vmap(model.init)(keys)
+            return p, jax.vmap(opt.init)(p)
+        p, o = jax.eval_shape(lanes)
         b = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x, (k, *x.shape)),
-            jax.tree_util.tree_map(jnp.asarray, batch_fn(0, 0)))
-        lr = jnp.zeros((k,), jnp.float32)
+            lambda x: jax.ShapeDtypeStruct((k, *np.shape(x)),
+                                           np.asarray(x).dtype),
+            batch_fn(0, 0))
+        lr = jax.ShapeDtypeStruct((k,), jnp.float32)
         return (p, o, b, lr)
 
     single_profile = None
@@ -318,9 +323,11 @@ def run_sweep(model: Model, tasks: Sequence[SweepTask], *,
             repack_policy=controller)
         try:
             stats = ex.run(queue)
-        except PoolStepError:   # pool-wide OOM: halve capacity, redo
-                                # unfinished (callback bugs propagate raw)
-            if policy.oom_backoff and ex.pool.capacity > policy.min_pack_factor:
+        except PoolStepError as e:  # pool-wide OOM: halve capacity, redo
+                                    # unfinished; any other cause (and
+                                    # callback bugs, raw) propagates
+            if (e.oom and policy.oom_backoff
+                    and ex.pool.capacity > policy.min_pack_factor):
                 backoffs += 1
                 # halve from where the pool actually WAS (adaptive repack
                 # may have moved it since dispatch)

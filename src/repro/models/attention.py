@@ -1,6 +1,7 @@
 """Attention: GQA, causal/bidirectional/sliding-window, KV cache, kernels.
 
-Three execution paths, selected by ``impl``:
+Three execution paths, selected by ``impl`` (``kernels.ops.resolve_impl``
+decides ``None``: "pallas" on a TPU, "xla" elsewhere):
   * ``"xla"``            — memory-efficient chunked online-softmax in pure
                            jnp (lax.scan over KV chunks). Default on CPU and
                            the path the multi-pod dry-run compiles.
@@ -18,11 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.models import layers
-
-
-def default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -201,7 +199,7 @@ def attention_block(params: dict, x: jax.Array, *,
       * kv_ctx given                       -> cross-attention onto kv_ctx
     kv_cache = {"k": (B,Smax,Hkv,D), "v": ..., "len": (B,) int32}.
     """
-    impl = impl or default_impl()
+    impl = kops.resolve_impl(impl)
     B, S, _ = x.shape
     cdt = x.dtype
     q = (x @ params["w_q"].astype(cdt)).reshape(B, S, num_heads, head_dim)
@@ -244,14 +242,12 @@ def attention_block(params: dict, x: jax.Array, *,
         out = sdpa_decode(q, k_new, v_new, valid)
         new_cache = {"k": k_new, "v": v_new, "len": new_len, "pos": pos_new}
     else:  # train / prefill
-        if impl in ("pallas", "pallas_interpret"):
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(
-                q, k, v, causal=causal, window=window,
-                interpret=(impl == "pallas_interpret"))
-        else:
+        if impl == "xla":
             out = sdpa_chunked(q, k, v, causal=causal, window=window,
                                prob_dtype=prob_dtype)
+        else:
+            out = kops.flash_attention(q, k, v, causal=causal,
+                                       window=window, impl=impl)
         if kv_cache is not None:  # prefill into cache (keep last Smax if S>Smax)
             Smax = kv_cache["k"].shape[1]
             if S >= Smax:

@@ -145,7 +145,10 @@ class Model:
         return (h @ w).astype(jnp.float32)
 
     # ----------------------------------------------------------------- loss
-    def loss(self, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    def logits(self, params, batch) -> Tuple[jax.Array, jax.Array]:
+        """Full-sequence forward with no cache: (logits (B,S,V) fp32,
+        router aux loss). The reference served tokens are checked
+        against."""
         cfg = self.cfg
         h, positions, mrope = self._embed_in(params, batch)
         enc_memory = None
@@ -154,7 +157,11 @@ class Model:
         h, _, aux = self._backbone(params, h, positions,
                                    mrope_positions=mrope,
                                    enc_memory=enc_memory)
-        logits = self._head(params, h)
+        return self._head(params, h), aux
+
+    def loss(self, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        cfg = self.cfg
+        logits, aux = self.logits(params, batch)
         ce = layers.cross_entropy_loss(logits, batch["labels"])
         coef = cfg.moe.router_aux_coef if cfg.moe else 0.0
         total = ce + coef * aux

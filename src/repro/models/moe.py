@@ -23,8 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-import repro.compat  # noqa: F401  (jax version shims)
-
 from repro.configs.base import MoEConfig
 from repro.models import layers
 

@@ -57,11 +57,13 @@ def _ssd_chunked_tagged(x, dt, A, B, C, *, chunk, init_state=None):
 
     # ---- intra-chunk (dual / attention-like form) ----
     CB = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)            # (b,nc,Q,Q)
-    # decay[i,j,h] = exp(la_i - la_j) for i >= j else 0
+    # decay[i,j,h] = exp(la_i - la_j) for i >= j else 0. Mask BEFORE the
+    # exp: above the diagonal la_i - la_j > 0 can overflow to inf, and
+    # where(tri, inf, 0) has a NaN gradient (inf * 0)
     diff = la[:, :, :, None, :] - la[:, :, None, :, :]    # (b,nc,Q,Q,nh)
     iq = jnp.arange(chunk)
     tri = (iq[:, None] >= iq[None, :])[None, None, :, :, None]
-    decay = jnp.where(tri, jnp.exp(diff), 0.0)
+    decay = jnp.exp(jnp.where(tri, diff, -jnp.inf))
     y_intra = jnp.einsum("bcij,bcijh,bcjhp->bcihp", CB, decay, xb)
 
     # ---- chunk-boundary states ----

@@ -22,15 +22,15 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-# ---- hardware constants (per chip; default preset is TPU v5e) --------------
+# ---- hardware constants (per chip; no default chip) -------------------------
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12        # bf16 FLOP/s
-    hbm_bw: float = 819e9             # bytes/s
-    ici_bw: float = 50e9              # bytes/s per link
-    hbm_bytes: float = 16e9
+    peak_flops: float                 # bf16 FLOP/s
+    hbm_bw: float                     # bytes/s
+    ici_bw: float                     # bytes/s per link
+    hbm_bytes: float
 
     @classmethod
     def for_arch(cls, arch: str) -> "HW":
@@ -44,6 +44,22 @@ class HW:
                 f"unknown arch {arch!r}; known presets: "
                 f"{sorted(_HW_PRESETS)}") from None
 
+    @classmethod
+    def for_device(cls, device=None) -> "HW":
+        """Peaks of the chip at hand (default: the first device), keyed
+        by its ``device_kind``. A kind with no preset — the CPU among
+        them — is an error: name an arch with ``for_arch`` instead."""
+        if device is None:
+            import jax
+            device = jax.devices()[0]
+        arch = _DEVICE_KINDS.get(device.device_kind)
+        if arch is None:
+            raise ValueError(
+                f"no roofline peaks for device kind {device.device_kind!r} "
+                f"({device.platform}); known kinds: "
+                f"{sorted(_DEVICE_KINDS)}")
+        return cls.for_arch(arch)
+
 
 # Public per-chip numbers: bf16 peak, HBM bandwidth, per-link ICI, HBM size.
 _HW_PRESETS: Dict[str, dict] = {
@@ -55,6 +71,14 @@ _HW_PRESETS: Dict[str, dict] = {
                 hbm_bytes=95e9),
     "v6e": dict(peak_flops=918e12, hbm_bw=1640e9, ici_bw=100e9,
                 hbm_bytes=32e9),
+}
+
+# ``jax.Device.device_kind`` -> preset (v5e reports "TPU v5 lite").
+_DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v6 lite": "v6e",
 }
 
 
@@ -154,7 +178,7 @@ class RooflineReport:
     peak_mem_bytes: int
     arg_bytes: int
     model_flops_global: float
-    hw: HW = dataclasses.field(default_factory=HW)
+    hw: HW
     xla_flops_per_dev: float = 0.0     # XLA cost_analysis cross-check
     xla_bytes_per_dev: float = 0.0
     bytes_by_tag: Dict[str, float] = dataclasses.field(default_factory=dict)
@@ -276,12 +300,11 @@ def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
                      kind: str, hw: Optional[HW] = None) -> RooflineReport:
     """Costs come from the trip-count-aware HLO analyzer (hlo_costs.py);
     XLA's cost_analysis undercounts scanned loop bodies (counts the body
-    once) and is kept only as a cross-check field."""
+    once) and is kept only as a cross-check field. ``hw`` defaults to
+    the chip at hand (``HW.for_device``)."""
     from repro.roofline.hlo_costs import analyze_hlo
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax <= 0.4.x wraps the dict
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     txt = compiled.as_text()
     hc = analyze_hlo(txt)
@@ -298,7 +321,7 @@ def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
         peak_mem_bytes=int(peak),
         arg_bytes=int(ma.argument_size_in_bytes),
         model_flops_global=model_flops(n_params, n_tokens, kind),
-        hw=hw or HW(),
+        hw=hw or HW.for_device(),
         xla_flops_per_dev=float(ca.get("flops", 0.0)),
         xla_bytes_per_dev=float(ca.get("bytes accessed", 0.0)),
         bytes_by_tag=dict(hc.bytes_by_tag),
@@ -334,10 +357,11 @@ class IntensityProfile:
     def from_compiled(cls, compiled, hw: Optional[HW] = None) -> "IntensityProfile":
         """Directly from a compiled XLA program (no model metadata needed)
         — the form the scheduler records at first dispatch, the way
-        ``MemoryAdmission.record_measured`` records HBM bytes."""
+        ``MemoryAdmission.record_measured`` records HBM bytes. ``hw``
+        defaults to the chip at hand (``HW.for_device``)."""
         from repro.roofline.hlo_costs import analyze_hlo
         hc = analyze_hlo(compiled.as_text())
-        hw = hw or HW()
+        hw = hw or HW.for_device()
         tc = hc.flops / hw.peak_flops
         tm = hc.hbm_bytes / hw.hbm_bw
         tl = hc.collective_operand_bytes / hw.ici_bw

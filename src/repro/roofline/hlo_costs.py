@@ -57,7 +57,7 @@ PALLAS_TILE_BUDGETS: Dict[str, float] = {
     "src/repro/kernels/flash_attention.py::flash_attention_fwd": 262144.0,
     "src/repro/kernels/fused_rmsnorm.py::fused_rmsnorm": 2101248.0,
     "src/repro/kernels/fused_rmsnorm.py::packed_rmsnorm": 2101248.0,
-    "src/repro/kernels/ssd_scan.py::ssd_scan": 725024.0,
+    "src/repro/kernels/ssd_scan.py::ssd_scan": 148992.0,
 }
 
 #: Allowed relative drift between the modeled bytes/step and the budget.

@@ -7,8 +7,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _copy(x_ref, o_ref):
     o_ref[...] = x_ref[...]
@@ -22,7 +20,7 @@ def copy_op(x):
         in_specs=[pl.BlockSpec((8, 128), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((8, 128), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((32, 512), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x)
 
@@ -51,6 +49,6 @@ def reduce_rows(x):
         out_specs=pl.BlockSpec((8, 128), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((32, 128), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(x)

@@ -6,8 +6,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _red(x_ref, o_ref, acc_scr):
     ki = pl.program_id(1)
@@ -33,6 +31,6 @@ def reduce_rows(x):
         out_specs=pl.BlockSpec((8, 128), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((32, 128), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(x)

@@ -10,7 +10,6 @@ import textwrap
 import jax
 import jax.numpy as jnp
 
-import repro.compat  # noqa: F401  (jax version shims)
 import numpy as np
 import pytest
 
@@ -25,7 +24,7 @@ def _run_sub(code: str, devices: int = 8) -> str:
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
     env["JAX_PLATFORMS"] = "cpu"
-    code = "import repro.compat  # jax version shims\n" + textwrap.dedent(code)
+    code = textwrap.dedent(code)
     out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, env=env, timeout=900)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
@@ -167,7 +166,7 @@ def test_small_multipod_dryrun_cell():
         import jax
         from repro.launch import dryrun
         from repro.launch.mesh import make_mesh
-        from repro.roofline.analysis import analyze_compiled
+        from repro.roofline.analysis import HW, analyze_compiled
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         with mesh:
             lowered, n_tok, kind, model = dryrun.lower_cell(
@@ -178,7 +177,8 @@ def test_small_multipod_dryrun_cell():
             c = lowered.compile()
         rep = analyze_compiled(c, arch="x", shape="train_4k",
                                mesh_name="2x2x2", chips=8,
-                               n_params=1e6, n_tokens=n_tok, kind="train")
+                               n_params=1e6, n_tokens=n_tok, kind="train",
+                               hw=HW.for_arch("v5e"))
         assert rep.flops_per_dev > 0
         assert rep.coll_operand_bytes > 0      # pod axis collectives exist
         ma = c.memory_analysis()

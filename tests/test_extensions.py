@@ -3,7 +3,6 @@ kernel, overlap collective matmul, config fidelity vs published sizes."""
 import jax
 import jax.numpy as jnp
 
-import repro.compat  # noqa: F401  (jax version shims)
 import numpy as np
 import pytest
 
@@ -66,7 +65,6 @@ def test_allgather_matmul_on_4_devices():
     env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     code = textwrap.dedent("""
-        import repro.compat  # jax version shims
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import allgather_matmul

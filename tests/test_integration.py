@@ -154,6 +154,64 @@ def test_run_sweep_periodic_checkpoints_and_raw_callback_errors(tmp_path):
                   early_stop=lambda t, s, l: 1 / 0)
 
 
+def _failing_pool_step(monkeypatch, error, when):
+    """Make every masked pool step raise ``error`` while ``when(capacity)``
+    holds, and run the real step otherwise."""
+    real = packing.masked_pool_step
+
+    def patched(step_fn, **kw):
+        step = real(step_fn, **kw)
+
+        def maybe_fail(params, *rest):
+            cap = jax.tree_util.tree_leaves(params)[0].shape[0]
+            if when(cap):
+                raise error
+            return step(params, *rest)
+        return maybe_fail
+    monkeypatch.setattr(packing, "masked_pool_step", patched)
+
+
+def _sweep_batch_fn(model):
+    from repro.data import SyntheticLM
+
+    def batch_fn(seed, step):
+        return SyntheticLM(vocab_size=model.cfg.vocab_size, seq_len=16,
+                           batch_size=2, seed=seed).batch(step)
+    return batch_fn
+
+
+def test_run_sweep_reraises_non_oom_step_error(monkeypatch):
+    """A pool step that fails for any reason but device OOM (a refused
+    kernel, a device fault) propagates: halving the pack would hide it."""
+    from repro.core.lanepool import PoolStepError
+    model = _tiny_lm()
+    _failing_pool_step(monkeypatch, ValueError("injected kernel refusal"),
+                       when=lambda cap: True)
+    tasks = [SweepTask(id=i, lr=1e-3, seed=i) for i in range(2)]
+    with pytest.raises(PoolStepError) as err:
+        run_sweep(model, tasks, batch_fn=_sweep_batch_fn(model), steps=2,
+                  max_pack=2)
+    assert not err.value.oom
+    assert isinstance(err.value.__cause__, ValueError)
+
+
+def test_run_sweep_backs_off_on_device_oom(monkeypatch):
+    """RESOURCE_EXHAUSTED from the packed step halves the pack and
+    re-runs the unfinished tasks; their losses match an unpacked run."""
+    model = _tiny_lm()
+    tasks = [SweepTask(id=i, lr=1e-3, seed=i) for i in range(2)]
+    alone = run_sweep(model, tasks, batch_fn=_sweep_batch_fn(model),
+                      steps=2, max_pack=1)
+    _failing_pool_step(
+        monkeypatch,
+        jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected OOM"),
+        when=lambda cap: cap > 1)
+    res = run_sweep(model, tasks, batch_fn=_sweep_batch_fn(model), steps=2,
+                    max_pack=2)
+    assert res.backoffs == 1 and res.pack_factor == 1
+    assert res.losses == alone.losses
+
+
 def test_llmapreduce_packed_vs_slotted():
     items = [jnp.float32(i) for i in range(9)]
     f = lambda x: x * x

@@ -69,7 +69,8 @@ def test_flash_attention_grad_matches_chunked():
     v = jax.random.normal(ks[2], (1, 64, 2, 32))
 
     def f_kernel(q, k, v):
-        return ops.flash_attention(q, k, v, True, 0, True).sum()
+        return ops.flash_attention(q, k, v, True, 0,
+                                   "pallas_interpret").sum()
 
     def f_ref(q, k, v):
         return ref.attention_ref(q, k, v, causal=True).sum()
@@ -146,10 +147,18 @@ def test_packed_gemm_vs_ref(J, M, K, N, bm, dtype):
 
 
 def test_ops_dispatch_on_cpu_uses_xla():
-    """On CPU without interpret, ops fall back to the jnp path."""
+    """On CPU with no impl named, ops take the jnp path; an explicit
+    "pallas" is never turned into XLA."""
+    assert ops.resolve_impl(None) == "xla"
+    assert ops.resolve_impl("pallas") == "pallas"
     q = jnp.ones((1, 16, 2, 8))
-    out = ops.flash_attention(q, q, q, True, 0, False)
+    out = ops.flash_attention(q, q, q, True, 0)
     assert out.shape == q.shape
+    jaxpr = str(jax.make_jaxpr(
+        lambda q: ops.flash_attention(q, q, q, True, 0, "pallas"))(q))
+    assert "pallas_call" in jaxpr
+    with pytest.raises(ValueError, match="unknown kernel impl"):
+        ops.resolve_impl("triton")
 
 
 # ---------------------------------------------------------------------------
@@ -217,9 +226,9 @@ def test_masked_ops_random_occupancy(rng):
     x = jax.random.normal(ks[0], (J, M, K), jnp.float32)
     w = jax.random.normal(ks[1], (J, K, N), jnp.float32)
     active = jnp.asarray(mask)
-    for interpret in (True, False):
-        out = ops.packed_matmul(x, w, active=active, interpret=interpret)
-        dense = ops.packed_matmul(x, w, interpret=interpret)
+    for impl in ("pallas_interpret", "xla"):
+        out = ops.packed_matmul(x, w, active=active, impl=impl)
+        dense = ops.packed_matmul(x, w, impl=impl)
         act, inact = np.flatnonzero(mask), np.flatnonzero(mask == 0)
         np.testing.assert_array_equal(np.asarray(out[act]),
                                       np.asarray(dense[act]))
@@ -308,7 +317,7 @@ def test_flash_native_masked_kernel_interpret(active, causal, window):
 
 
 def test_flash_native_masked_kernel_grads_interpret():
-    """ops.flash_attention's masked Pallas path (interpret=True) runs
+    """ops.flash_attention's masked Pallas path (interpret mode) runs
     the in-kernel gate forward and the masked-sdpa recompute backward;
     grads match dense on active lanes and are exact zeros elsewhere."""
     B, S, H, D = 4, 32, 2, 16
@@ -320,11 +329,11 @@ def test_flash_native_masked_kernel_grads_interpret():
 
     g_masked = jax.grad(
         lambda q_: ops.flash_attention(q_, k, v, causal=True,
-                                       interpret=True,
+                                       impl="pallas_interpret",
                                        active=active).sum())(q)
     g_dense = jax.grad(
         lambda q_: ops.flash_attention(q_, k, v, causal=True,
-                                       interpret=True).sum())(q)
+                                       impl="pallas_interpret").sum())(q)
     for b in range(B):
         if int(active[b]):
             np.testing.assert_allclose(np.asarray(g_masked[b]),

@@ -329,11 +329,11 @@ def test_kernel_mode_pool_freezes_inactive_lanes():
 
     def pool_step(params, opt_state, batch, hp, active):
         pred = kops.packed_matmul(batch["x"], params["w"], active=active,
-                                  interpret=True)
+                                  impl="pallas_interpret")
         err = pred - batch["y"]
         xt = jnp.swapaxes(batch["x"], -1, -2)
         grad = kops.packed_matmul(xt, err, active=active,
-                                  interpret=True) / batch["x"].shape[-2]
+                                  impl="pallas_interpret") / batch["x"].shape[-2]
         loss = jnp.mean(err * err, axis=(-1, -2))
         return ({"w": params["w"] - hp.reshape(-1, 1, 1) * grad},
                 {"m": opt_state["m"] * 0.9 + loss * 0.1}, {"loss": loss})

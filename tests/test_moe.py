@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 
-import repro.compat  # noqa: F401  (jax version shims)
 import numpy as np
 import pytest
 

@@ -111,3 +111,46 @@ def test_predict_oom_guards_the_48_job_case():
                       output_bytes=0, flops=0, bytes_accessed=0)
     # 48 jobs × 4GB > 64GB of two V100s -> guard fires BEFORE launch
     assert autotune.predict_oom(p, hbm_budget=64e9)
+
+
+def test_device_hbm_budget_reads_memory_stats():
+    """The sweep's budget is the device's free HBM; a backend that
+    reports no memory statistics (the CPU) is an error, not a guess."""
+    from repro.core.monitor import device_hbm_budget
+
+    class _Dev:
+        platform = "tpu"
+
+        def memory_stats(self):
+            return {"bytes_limit": 16_000, "bytes_in_use": 1_500}
+    assert device_hbm_budget(_Dev()) == 14_500
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        device_hbm_budget()
+
+
+def test_sweep_probe_args_are_shapes_only(monkeypatch):
+    """auto_nppn's probes compile from shapes: building the k-lane
+    arguments allocates no parameters or optimizer state."""
+    from repro import configs
+    from repro.data import SyntheticLM
+    from repro.launch.sweep import SweepTask, run_sweep
+    from repro.models import build_model
+    model = build_model(configs.get("mamba2-130m").reduced())
+    seen = {}
+    real = autotune.auto_nppn
+
+    def spy(make_packed, example_args_fn, *a, **kw):
+        seen["args"] = example_args_fn(4)
+        return real(make_packed, example_args_fn, *a, **kw)
+    monkeypatch.setattr(autotune, "auto_nppn", spy)
+    res = run_sweep(
+        model, [SweepTask(id=i, lr=1e-3, seed=i) for i in range(2)],
+        batch_fn=lambda seed, step: SyntheticLM(
+            vocab_size=model.cfg.vocab_size, seq_len=32, batch_size=2,
+            seed=seed).batch(step),
+        steps=1, hbm_budget=1e9)
+    leaves = jax.tree_util.tree_leaves(seen["args"])
+    assert leaves and all(isinstance(x, jax.ShapeDtypeStruct)
+                          for x in leaves)
+    assert all(x.shape[0] == 4 for x in leaves)
+    assert res.pack_factor == 2 and len(res.losses[1]) == 1

@@ -20,7 +20,11 @@ from repro.roofline.analysis import HW, IntensityProfile
 # ---------------------------------------------------------------------------
 
 def test_hw_for_arch_presets():
-    assert HW.for_arch("v5e") == HW()     # default preset == default HW
+    class _Dev:                           # a v5e as jax reports it
+        platform, device_kind = "tpu", "TPU v5 lite"
+    assert HW.for_device(_Dev()) == HW.for_arch("v5e")
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        HW.for_device()                   # the CPU has no preset
     for arch in ("v4", "v5e", "v5p", "v6e"):
         hw = HW.for_arch(arch)
         assert hw.peak_flops > 0 and hw.hbm_bw > 0
@@ -60,8 +64,9 @@ def test_intensity_profile_from_compiled_decode_vs_train_ordering():
     # elementwise: one flop per operand byte -> memory-bound
     ew = jax.jit(lambda a, b: a + b).lower(
         jnp.zeros((512, 512)), jnp.zeros((512, 512))).compile()
-    p_mm = IntensityProfile.from_compiled(mm)
-    p_ew = IntensityProfile.from_compiled(ew)
+    hw = HW.for_arch("v5e")
+    p_mm = IntensityProfile.from_compiled(mm, hw)
+    p_ew = IntensityProfile.from_compiled(ew, hw)
     assert p_ew.memory_bound_frac > p_mm.memory_bound_frac
     assert p_mm.arithmetic_intensity > p_ew.arithmetic_intensity
 

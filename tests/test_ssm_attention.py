@@ -36,6 +36,27 @@ def test_ssd_chunked_matches_recurrence(rng):
                                atol=2e-4)
 
 
+def test_ssd_chunked_grads_finite_when_decay_overflows():
+    """Within a chunk the log-decay spread can pass f32's exp range
+    (dt * |A| summed over Q steps > 88, as at mamba2-130m's init with
+    A down to -16 and Q = 128). The masked upper triangle must not turn
+    that overflow into NaN gradients."""
+    b, S, nh, hd, N, Q = 1, 32, 2, 4, 8, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(ks[0], (b, S, nh, hd))
+    dt = jnp.ones((b, S, nh))
+    A = jnp.array([-16.0, -1.0])
+    B = jax.random.normal(ks[1], (b, S, N))
+    C = jax.random.normal(ks[2], (b, S, N))
+
+    def loss(x, dt, B, C):
+        y, st = ssm.ssd_chunked(x, dt, A, B, C, chunk=Q)
+        return jnp.sum(y ** 2) + jnp.sum(st)
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))(x, dt, B, C)
+    for g in grads:
+        assert bool(jnp.all(jnp.isfinite(g)))
+
+
 def test_mamba2_prefill_then_decode_continues_exactly():
     """Decode from the prefill state == running the longer sequence."""
     cfg = SSMConfig(state_dim=16, head_dim=8, expand=2, conv_width=4,
