@@ -52,7 +52,6 @@ class FaultPolicy:
     speculative_stragglers: bool = True # duplicate a straggling lane onto a
                                         # free pool slot, first-result-wins
                                         # (lanepool.RefillExecutor)
-    straggler_ratio: float = 1.5        # EWMA step time vs median (monitor)
     checkpoint_every: int = 0           # steps (sweep per-task saves) and
                                         # rounds (scheduler gang cursors);
                                         # 0 = only on completion/preempt

@@ -29,10 +29,17 @@ node level. This module closes it at the LANE level:
 
   * speculative straggler re-execution (``FaultPolicy.
     speculative_stragglers``): when the queue has drained and free lanes
-    remain, a lane flagged by ``stragglers_fn`` (RunMonitor.stragglers)
+    remain, a lane flagged by a caller-supplied ``stragglers_fn``
     is DUPLICATED onto a free slot — twin lanes advance the same task
     from the same state, first result wins, the loser is cancelled
     without a second ``on_finish``.
+
+Each executor iteration is traced (core/monitor.span): a
+``lanepool.iteration`` span holds ``lanepool.refill`` (``lanepool.init``,
+``lanepool.attach``), ``lanepool.batch``, ``lanepool.step`` (the
+dispatch), ``lanepool.wait`` (the host waiting for the step, through the
+first read lane's metrics) and ``lanepool.read`` (per-lane metrics,
+detach, ``on_finish``).
 
 Semantics guarantee (tested): a task that detaches and re-attaches on
 another lane produces bit-identical losses to an uninterrupted run —
@@ -54,6 +61,7 @@ import numpy as np
 
 from repro.checkpoint import checkpointer as ck
 from repro.core import packing
+from repro.core.monitor import span
 
 
 class PoolStepError(RuntimeError):
@@ -354,7 +362,7 @@ class RefillExecutor:
     canonical, same rule as a preemption drain).
 
     Speculative stragglers: with ``speculative`` set and a
-    ``stragglers_fn`` (e.g. RunMonitor.stragglers) naming suspect lanes,
+    caller-supplied ``stragglers_fn`` naming suspect lanes,
     a flagged lane's task is duplicated onto a free slot once the queue
     has drained (never displacing queued work). The twin advances a COPY
     of the lane's current state; first result wins, the loser is
@@ -370,7 +378,6 @@ class RefillExecutor:
     def __init__(self, pool: LanePool, *,
                  on_metrics: Optional[Callable[[LaneTask, int, Any], bool]] = None,
                  on_finish: Optional[Callable[[LaneTask, Any, Any], None]] = None,
-                 on_step_start: Optional[Callable[[], None]] = None,
                  on_step: Optional[Callable[[int, int, int], None]] = None,
                  checkpoint_every: int = 0,
                  on_checkpoint: Optional[Callable[[LaneTask, Any, Any],
@@ -385,8 +392,7 @@ class RefillExecutor:
         self.pool = pool
         self.on_metrics = on_metrics
         self.on_finish = on_finish
-        self.on_step_start = on_step_start      # brackets pool.step for
-        self.on_step = on_step          # timing: (global, active, capacity)
+        self.on_step = on_step          # (global, active, capacity)
         self.checkpoint_every = checkpoint_every
         self.on_checkpoint = on_checkpoint
         self.should_preempt = should_preempt
@@ -425,12 +431,16 @@ class RefillExecutor:
             attached = False
             while queue and not attached:
                 t = queue.popleft()
-                params, opt_state = t.init_fn()
-                if t.step_done >= t.steps:      # zero budget / fully
-                    if self.on_finish is not None:   # checkpoint-restored
-                        self.on_finish(t, params, opt_state)
-                    continue
-                self.pool.attach(lane, t.id, params, opt_state, t.hparams)
+                with span("lanepool.refill", task=t.id, lane=lane):
+                    with span("lanepool.init"):
+                        params, opt_state = t.init_fn()
+                    if t.step_done >= t.steps:      # zero budget / fully
+                        if self.on_finish is not None:   # checkpoint-restored
+                            self.on_finish(t, params, opt_state)
+                        continue
+                    with span("lanepool.attach"):
+                        self.pool.attach(lane, t.id, params, opt_state,
+                                         t.hparams)
                 lane_task[lane] = t
                 stats.attaches += 1
                 attached = True
@@ -438,15 +448,16 @@ class RefillExecutor:
                 break
 
     def _stacked_batch(self, lane_task: List[Optional[LaneTask]]) -> Any:
-        live = {i: jax.tree_util.tree_map(jnp.asarray,
-                                          t.batch_fn(t.step_done))
-                for i, t in enumerate(lane_task) if t is not None}
-        if self._zero_batch is None:
-            template = next(iter(live.values()))
-            self._zero_batch = jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape, x.dtype), template)
-        return packing.stack_trees([live.get(i, self._zero_batch)
-                                    for i in range(len(lane_task))])
+        with span("lanepool.batch"):
+            live = {i: jax.tree_util.tree_map(jnp.asarray,
+                                              t.batch_fn(t.step_done))
+                    for i, t in enumerate(lane_task) if t is not None}
+            if self._zero_batch is None:
+                template = next(iter(live.values()))
+                self._zero_batch = jax.tree_util.tree_map(
+                    lambda x: jnp.zeros(x.shape, x.dtype), template)
+            return packing.stack_trees([live.get(i, self._zero_batch)
+                                        for i in range(len(lane_task))])
 
     def _speculate(self, queue: deque, lane_task: List[Optional[LaneTask]],
                    stats: RefillStats):
@@ -573,80 +584,97 @@ class RefillExecutor:
         lane_task: List[Optional[LaneTask]] = [None] * pool.capacity
         stats = RefillStats()
         while queue or any(t is not None for t in lane_task):
-            self._refill(queue, lane_task, stats)
-            self._speculate(queue, lane_task, stats)
-            if self._zero_batch is None and all(
-                    t is None for t in lane_task):
-                break                   # nothing attachable (empty task set)
-            if self.record_history:
-                for lane, t in enumerate(lane_task):
-                    if t is not None:
-                        self.history.append((stats.global_steps, lane, t.id))
-            batch = self._stacked_batch(lane_task)
-            if self.on_step_start is not None:
-                self.on_step_start()
-            metrics = pool.step(batch)
-            n_attached = sum(1 for t in lane_task if t is not None)
-            n_twin = sum(1 for l in self._spec_lanes
-                         if lane_task[l] is not None)
-            stats.lane_steps += n_attached - n_twin
-            stats.spec_lane_steps += n_twin
-            if self.on_step is not None:    # occupancy counts twins: they
-                self.on_step(stats.global_steps,   # really hold lanes
-                             n_attached, pool.capacity)
-            stats.global_steps += 1
-            # retire primaries BEFORE speculative twins: when both hit
-            # budget in the same pass (always, in a lockstep pool) the
-            # primary must deliver the final on_metrics/on_finish and
-            # cancel the twin — a twin winning a scan-order tie would
-            # silently swallow the task's last metrics sample
-            order = [l for l in range(len(lane_task))
-                     if l not in self._spec_lanes]
-            order += [l for l in range(len(lane_task))
-                      if l in self._spec_lanes]
-            for lane in order:
-                t = lane_task[lane]
-                if t is None:
-                    continue
-                is_twin = lane in self._spec_lanes
-                stop = False
-                if self.on_metrics is not None and not is_twin:
-                    lm = packing.lane_slice(metrics, lane)
-                    stop = bool(self.on_metrics(t, t.step_done, lm))
-                t.step_done += 1
-                if stop:
-                    t.stopped_early = True
-                if t.step_done >= t.steps or stop:
-                    params, opt_state = pool.detach(lane)
-                    lane_task[lane] = None
-                    self._cancel_twin(lane, lane_task, stats)
-                    if self.on_finish is not None:
-                        self.on_finish(t, params, opt_state)
-                elif (self.checkpoint_every
-                      and self.on_checkpoint is not None
-                      and not is_twin
-                      and t.step_done % self.checkpoint_every == 0):
-                    self.on_checkpoint(
-                        t, packing.tree_get_lane(pool.params, lane),
-                        packing.tree_get_lane(pool.opt_state, lane))
-            if self._preempt_requested or (
-                    self.should_preempt is not None
-                    and self.should_preempt(stats)):
-                self._preempt_requested = False
-                self.snapshot = self._drain(queue, lane_task, stats)
-                stats.preempted = True
-                break
-            # online elastic repack: telemetry in, capacity decision out
-            if self.repack is not None:
-                self.repack.observe(stats.global_steps, n_attached,
-                                    pool.capacity, len(queue))
-                live = sum(1 for t in lane_task if t is not None)
-                new_cap = self.repack.decide(stats.global_steps,
-                                             pool.capacity, len(queue), live)
-                if new_cap is not None and new_cap != pool.capacity:
-                    lane_task = self._repack(queue, lane_task, new_cap,
-                                             stats)
-                    pool = self.pool
+            with span("lanepool.iteration",
+                      capacity=pool.capacity) as counts:
+                self._refill(queue, lane_task, stats)
+                self._speculate(queue, lane_task, stats)
+                if self._zero_batch is None and all(
+                        t is None for t in lane_task):
+                    break               # nothing attachable (empty task set)
+                if self.record_history:
+                    for lane, t in enumerate(lane_task):
+                        if t is not None:
+                            self.history.append(
+                                (stats.global_steps, lane, t.id))
+                batch = self._stacked_batch(lane_task)
+                with span("lanepool.step"):
+                    metrics = pool.step(batch)
+                n_attached = sum(1 for t in lane_task if t is not None)
+                n_twin = sum(1 for l in self._spec_lanes
+                             if lane_task[l] is not None)
+                counts["lanes"] = n_attached
+                stats.lane_steps += n_attached - n_twin
+                stats.spec_lane_steps += n_twin
+                if self.on_step is not None:    # occupancy counts twins: they
+                    self.on_step(stats.global_steps,   # really hold lanes
+                                 n_attached, pool.capacity)
+                stats.global_steps += 1
+                # retire primaries BEFORE speculative twins: when both hit
+                # budget in the same pass (always, in a lockstep pool) the
+                # primary must deliver the final on_metrics/on_finish and
+                # cancel the twin — a twin winning a scan-order tie would
+                # silently swallow the task's last metrics sample
+                order = [l for l in range(len(lane_task))
+                         if l not in self._spec_lanes]
+                order += [l for l in range(len(lane_task))
+                          if l in self._spec_lanes]
+                # the first lane read is sliced before the wait, as the
+                # reads would slice it: its slice queues behind the step
+                # on the device, so waiting adds no round trip
+                first = None
+                if self.on_metrics is not None:
+                    first = next((l for l in order
+                                  if lane_task[l] is not None
+                                  and l not in self._spec_lanes), None)
+                head = (None if first is None
+                        else packing.lane_slice(metrics, first))
+                with span("lanepool.wait"):
+                    jax.block_until_ready(head)
+                with span("lanepool.read", lanes=n_attached - n_twin):
+                    for lane in order:
+                        t = lane_task[lane]
+                        if t is None:
+                            continue
+                        is_twin = lane in self._spec_lanes
+                        stop = False
+                        if self.on_metrics is not None and not is_twin:
+                            lm = (head if lane == first
+                                  else packing.lane_slice(metrics, lane))
+                            stop = bool(self.on_metrics(t, t.step_done, lm))
+                        t.step_done += 1
+                        if stop:
+                            t.stopped_early = True
+                        if t.step_done >= t.steps or stop:
+                            params, opt_state = pool.detach(lane)
+                            lane_task[lane] = None
+                            self._cancel_twin(lane, lane_task, stats)
+                            if self.on_finish is not None:
+                                self.on_finish(t, params, opt_state)
+                        elif (self.checkpoint_every
+                              and self.on_checkpoint is not None
+                              and not is_twin
+                              and t.step_done % self.checkpoint_every == 0):
+                            self.on_checkpoint(
+                                t, packing.tree_get_lane(pool.params, lane),
+                                packing.tree_get_lane(pool.opt_state, lane))
+                if self._preempt_requested or (
+                        self.should_preempt is not None
+                        and self.should_preempt(stats)):
+                    self._preempt_requested = False
+                    self.snapshot = self._drain(queue, lane_task, stats)
+                    stats.preempted = True
+                    break
+                # online elastic repack: telemetry in, capacity decision out
+                if self.repack is not None:
+                    self.repack.observe(stats.global_steps, n_attached,
+                                        pool.capacity, len(queue))
+                    live = sum(1 for t in lane_task if t is not None)
+                    new_cap = self.repack.decide(
+                        stats.global_steps, pool.capacity, len(queue), live)
+                    if new_cap is not None and new_cap != pool.capacity:
+                        lane_task = self._repack(queue, lane_task, new_cap,
+                                                 stats)
+                        pool = self.pool
         stats.n_traces = self._trace_base + pool.n_traces
         return stats
 

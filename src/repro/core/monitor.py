@@ -1,18 +1,24 @@
 """LLload analogue: resource monitoring for triples jobs [paper §II, ref 21].
 
 The paper's workflow: run LLload, read CPU/GPU load + memory, choose NPPN.
-Two monitors here:
+What this module keeps:
 
   * static  — ahead-of-time prediction from the compiled program
     (memory_analysis / cost_analysis). This is what auto_nppn consumes.
-  * runtime — per-step wall-time and live-buffer tracking per lane;
-    produces the LLload-style table and flags stragglers.
+  * spans   — the program's runtime tracing: ``span(name, **counts)``
+    around the work at each layer boundary, kept in one bounded
+    in-memory log (``span_log()``) and, while a profiler trace runs, on
+    the profiler's host clock beside the device's ops.
+  * gauges  — the per-tenant LLload table the scheduler keeps.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+import itertools
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -38,11 +44,6 @@ class StaticProfile:
     def fits(self, hbm_budget: float, headroom: float = 0.95) -> bool:
         return self.resident_bytes <= hbm_budget * headroom
 
-    def load_proxy(self, peak_flops: float, step_time_s: float) -> float:
-        """GPU-load analogue: achieved FLOP/s over peak (the paper's
-        'GPU load' y-axis, Figs 2/7)."""
-        return self.flops / step_time_s / peak_flops
-
 
 def profile_compiled(compiled) -> StaticProfile:
     ma = compiled.memory_analysis()
@@ -59,6 +60,61 @@ def profile_compiled(compiled) -> StaticProfile:
 def profile_fn(fn, *example_args, **kw) -> StaticProfile:
     compiled = jax.jit(fn, **kw).lower(*example_args).compile()
     return profile_compiled(compiled)
+
+
+# ---------------------------------------------------------------------------
+# spans: the program's runtime tracing
+# ---------------------------------------------------------------------------
+
+#: entries the span log keeps (the oldest go first); a 30 s serving window
+#: writes about 3k, a sweep window under 1k
+SPAN_LOG_CAPACITY = 65536
+
+
+class Span(NamedTuple):
+    """One closed span. ``index`` numbers spans in the order they opened;
+    ``parent`` is the index of the span that was open around this one
+    (None at the top). ``counts`` is the work done at that boundary."""
+    index: int
+    name: str
+    start_ns: int               # time.perf_counter_ns()
+    end_ns: int
+    parent: Optional[int]
+    counts: Dict[str, Any]
+
+
+_spans: "collections.deque[Span]" = collections.deque(
+    maxlen=SPAN_LOG_CAPACITY)
+_span_index = itertools.count()
+_open: List[int] = []           # indices of the open spans, innermost last
+
+
+@contextlib.contextmanager
+def span(name: str, **counts):
+    """Time the body as span ``name`` and log it when the body ends, also
+    when it raises (the exception propagates). The span is also a
+    ``jax.profiler.TraceAnnotation``, so that while a profiler trace runs
+    it lies on the host plane under ``name``, on the device ops' clock,
+    with ``counts`` as its metadata. The span yields its ``counts`` dict:
+    a count known only inside the body is added there, and reaches the
+    log but not the trace."""
+    parent = _open[-1] if _open else None
+    index = next(_span_index)
+    _open.append(index)
+    start = time.perf_counter_ns()  # lint: disable=DET001(span timing for traces; no decision reads it)
+    try:
+        with jax.profiler.TraceAnnotation(name, **counts):
+            yield counts
+    finally:
+        end = time.perf_counter_ns()  # lint: disable=DET001(span timing for traces; no decision reads it)
+        _open.pop()
+        _spans.append(Span(index, name, start, end, parent, counts))
+
+
+def span_log() -> List[Span]:
+    """The closed spans in the log, in the order they closed (a child
+    before its parent). The caller writes them out where it wants."""
+    return list(_spans)
 
 
 # ---------------------------------------------------------------------------
@@ -417,17 +473,3 @@ class TenantGauges:
                 f"{g.jobs_preempted:>3d} {g.jobs_resumed:>3d} {mw:>9.1f}")
         return "\n".join(lines)
 
-
-def llload_table(node_name: str, profiles: Dict[str, StaticProfile],
-                 hbm_total: float, step_times: Dict[str, float],
-                 peak_flops: float) -> str:
-    """Render the LLload-style snapshot (paper Fig. 1) for compiled jobs."""
-    lines = [f"{'JOB':24s} {'GPUMEM-USED':>12s} {'GPUMEM-FREE':>12s} "
-             f"{'GPULOAD':>8s}"]
-    for name, p in profiles.items():
-        used = p.resident_bytes
-        load = (p.load_proxy(peak_flops, step_times[name])
-                if name in step_times else float("nan"))
-        lines.append(f"{name:24s} {used/1e9:10.1f}GB {(hbm_total-used)/1e9:10.1f}GB "
-                     f"{load:8.2f}")
-    return "\n".join(lines)

@@ -19,6 +19,13 @@ core/lanepool.py):
 Lanes are independent under vmap, so a request's tokens are identical
 whatever co-residents it decodes next to (prompts are left-padded to one
 fixed length per ``run``).
+
+``run`` is traced (core/monitor.span): ``serve.pool`` (the first prefill
+and the stacked cache pool), then per loop iteration ``serve.step``
+holding ``serve.decode`` (the step's dispatch), ``serve.wait`` (the host
+waiting for the next tokens), ``serve.readback`` (their copy to the
+host) and one ``serve.join`` per joining request (``serve.prefill``,
+``serve.attach``, then the read of its first token).
 """
 from __future__ import annotations
 
@@ -30,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packing
+from repro.core.monitor import span
 from repro.models.model import Model
 
 
@@ -143,20 +151,27 @@ class BatchServer:
 
         # seed the pool from the first prefill so every leaf has its lane
         # axis before any swap (shapes fixed until an adaptive resize)
-        first0, cache0 = prefill_one(queue[0])
-        pool_cache = packing.stack_trees([cache0] * C)
+        with span("serve.pool", lanes=C):
+            first0, cache0 = prefill_one(queue[0])
+            pool_cache = packing.stack_trees([cache0] * C)
         cur = np.zeros((C, 1, 1), np.int32)          # per-lane (B=1, T=1)
         pos = np.full((C, 1), S_pad, np.int32)
         lane_req: List[Optional[Request]] = [None] * C
 
         def attach(lane: int, r: Request, first=None, cache=None):
             nonlocal pool_cache
-            if first is None:
-                first, cache = prefill_one(r)
-            pool_cache = packing.tree_set_lane(pool_cache, lane, cache)
-            cur[lane, 0, 0] = int(first[0])
-            pos[lane, 0] = S_pad
-            lane_req[lane] = r
+            with span("serve.join", request=r.id, lane=lane):
+                if first is None:
+                    with span("serve.prefill"):
+                        first, cache = prefill_one(r)
+                with span("serve.attach"):
+                    pool_cache = packing.tree_set_lane(pool_cache, lane,
+                                                       cache)
+                # the first token is read after the swap is dispatched,
+                # so that the swap queues behind the prefill on the device
+                cur[lane, 0, 0] = int(first[0])
+                pos[lane, 0] = S_pad
+                lane_req[lane] = r
 
         def resize(new_c: int):
             """Compact live lanes into a pool of ``new_c`` lanes (pure
@@ -186,45 +201,54 @@ class BatchServer:
                 attach(lane, queue.pop(0))
 
         while True:
-            # emit + retire phase: the token each active lane carries came
-            # from the PREVIOUS step (or its prefill). Record it, and
-            # retire lanes whose budget is now exhausted BEFORE stepping —
-            # stepping a finished lane would produce a token nobody
-            # consumes (one wasted vmapped step per request).
-            for lane, r in enumerate(lane_req):
-                if r is None:
-                    continue
-                r.out.append(int(cur[lane, 0, 0]))
-                self.stats.lane_steps += 1
-                if len(r.out) >= r.max_new:
-                    r.done = True        # lane frees NOW — no wave barrier
-                    lane_req[lane] = None
-            n_live = sum(1 for r in lane_req if r is not None)
-            if n_live == 0 and not queue:
-                break
-            if self.adaptive_lanes:
-                demand = n_live + len(queue)
-                desired = 1 << (max(1, demand) - 1).bit_length()
-                desired = min(self.lanes, max(desired, n_live, 1))
-                if desired < C:
-                    resize(desired)
-            if n_live:
-                active = np.array([r is not None for r in lane_req])
-                logits, pool_cache = self._step(
-                    self.params,
-                    {"tokens": jnp.asarray(cur), "pos": jnp.asarray(pos)},
-                    pool_cache)
-                nxt = np.asarray(jnp.argmax(logits, -1), np.int32)  # (C, 1)
-                self.stats.global_steps += 1
-                self.stats.lane_slots += C
-                cur[active, 0, 0] = nxt[active, 0]
-                pos[active, 0] += 1      # inactive lanes stay frozen
-            # refill phase — strictly AFTER the step: a joiner's first
-            # token (from its prefill) sits in ``cur`` and must be
-            # emitted next iteration before the lane is ever stepped;
-            # attaching pre-step would let the step consume and overwrite
-            # it, shifting the request's whole output by one
-            for lane, r in enumerate(lane_req):
-                if r is None and queue:  # waiting request joins mid-decode
-                    attach(lane, queue.pop(0))
+            with span("serve.step",
+                      tokens=sum(r is not None for r in lane_req)):
+                # emit + retire phase: the token each active lane carries
+                # came from the PREVIOUS step (or its prefill). Record it,
+                # and retire lanes whose budget is now exhausted BEFORE
+                # stepping — stepping a finished lane would produce a
+                # token nobody consumes (one wasted vmapped step per
+                # request).
+                for lane, r in enumerate(lane_req):
+                    if r is None:
+                        continue
+                    r.out.append(int(cur[lane, 0, 0]))
+                    self.stats.lane_steps += 1
+                    if len(r.out) >= r.max_new:
+                        r.done = True    # lane frees NOW — no wave barrier
+                        lane_req[lane] = None
+                n_live = sum(1 for r in lane_req if r is not None)
+                if n_live == 0 and not queue:
+                    break
+                if self.adaptive_lanes:
+                    demand = n_live + len(queue)
+                    desired = 1 << (max(1, demand) - 1).bit_length()
+                    desired = min(self.lanes, max(desired, n_live, 1))
+                    if desired < C:
+                        resize(desired)
+                if n_live:
+                    active = np.array([r is not None for r in lane_req])
+                    with span("serve.decode"):
+                        logits, pool_cache = self._step(
+                            self.params,
+                            {"tokens": jnp.asarray(cur),
+                             "pos": jnp.asarray(pos)},
+                            pool_cache)
+                        nxt = jnp.argmax(logits, -1)            # (C, 1)
+                    with span("serve.wait"):
+                        jax.block_until_ready(nxt)
+                    with span("serve.readback"):
+                        nxt = np.asarray(nxt, np.int32)
+                    self.stats.global_steps += 1
+                    self.stats.lane_slots += C
+                    cur[active, 0, 0] = nxt[active, 0]
+                    pos[active, 0] += 1      # inactive lanes stay frozen
+                # refill phase — strictly AFTER the step: a joiner's first
+                # token (from its prefill) sits in ``cur`` and must be
+                # emitted next iteration before the lane is ever stepped;
+                # attaching pre-step would let the step consume and
+                # overwrite it, shifting the request's whole output by one
+                for lane, r in enumerate(lane_req):
+                    if r is None and queue:  # joins mid-decode
+                        attach(lane, queue.pop(0))
         return results

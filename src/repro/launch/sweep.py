@@ -33,7 +33,7 @@ from repro.core import repack as rp
 from repro.core.faults import FaultPolicy
 from repro.core.lanepool import (LanePool, LaneTask, PoolStepError,
                                  RefillExecutor, RefillStats)
-from repro.core.monitor import RunMonitor, TenantGauges
+from repro.core.monitor import TenantGauges, span
 from repro.core.tenancy import MemoryAdmission
 from repro.launch.train import make_train_step
 from repro.models.model import Model
@@ -114,11 +114,10 @@ def run_sweep(model: Model, tasks: Sequence[SweepTask], *,
     Speculative stragglers (``FaultPolicy.speculative_stragglers``):
     flagged lanes duplicate onto free pool slots, first result wins.
     On THIS substrate's single-host lockstep pool every lane steps in
-    one compiled call, so per-lane step-time skew cannot arise and the
-    default monitor signal never flags anyone — pass ``stragglers_fn``
-    to supply a real signal (per-device pools, external telemetry, or
-    tests); the default stays ``RunMonitor.stragglers`` (EWMA per-lane
-    times, live once lane times exist).
+    one compiled call, so per-lane step-time skew cannot arise: the
+    sweep has no default signal and flags no lane unless
+    ``stragglers_fn`` supplies one (per-device pools, external
+    telemetry, or tests).
 
     Online elastic repacking (``adaptive_pack`` — DESIGN.md §9): skip
     the static auto_nppn probe entirely, start at the conservative
@@ -164,37 +163,40 @@ def run_sweep(model: Model, tasks: Sequence[SweepTask], *,
 
     single_profile = None
     repack_pol = repack_policy or rp.RepackPolicy()
-    if adaptive_pack:
-        # conservative start; the controller converges online (no probe)
-        pack = max(1, min(repack_pol.start_capacity, max_pack, n))
-    elif hbm_budget is not None:
-        decision = autotune.auto_nppn(make_packed, example_args,
-                                      hbm_budget, max_factor=max_pack)
-        pack = decision.nppn_per_chip
-        single_profile = decision.profile_single
-    else:
-        pack = min(max_pack, n)
-
-    # ---- memory-aware admission: footprint caps the pool up front ----
-    bytes_per_lane = 0
-    admission_capped = False
-    if admission is not None:
-        if single_profile is None:      # auto_nppn already probed k=1
-            compiled = jax.jit(make_packed(1)).lower(*example_args(1)).compile()
-            bytes_per_lane = packing.memory_per_lane(compiled)
+    with span("sweep.autotune") as counts:
+        if adaptive_pack:
+            # conservative start; the controller converges online
+            # (no probe)
+            pack = max(1, min(repack_pol.start_capacity, max_pack, n))
+        elif hbm_budget is not None:
+            decision = autotune.auto_nppn(make_packed, example_args,
+                                          hbm_budget, max_factor=max_pack)
+            pack = decision.nppn_per_chip
+            single_profile = decision.profile_single
         else:
-            bytes_per_lane = single_profile.resident_bytes
-        try:
-            cap = admission.require_fits(bytes_per_lane)
-        except MemoryError as e:
-            raise MemoryError(f"tenant {tenant!r}: {e}") from None
-        if pack > cap:
-            pack, admission_capped = cap, True
+            pack = min(max_pack, n)
+
+        # ---- memory-aware admission: footprint caps the pool up front ----
+        bytes_per_lane = 0
+        admission_capped = False
+        if admission is not None:
+            if single_profile is None:      # auto_nppn already probed k=1
+                compiled = jax.jit(make_packed(1)).lower(
+                    *example_args(1)).compile()
+                bytes_per_lane = packing.memory_per_lane(compiled)
+            else:
+                bytes_per_lane = single_profile.resident_bytes
+            try:
+                cap = admission.require_fits(bytes_per_lane)
+            except MemoryError as e:
+                raise MemoryError(f"tenant {tenant!r}: {e}") from None
+            if pack > cap:
+                pack, admission_capped = cap, True
+        counts["pack"] = pack
 
     # ---- continuous refill over a persistent lane pool ----
     t0 = time.perf_counter()
     losses: Dict[int, List[float]] = {t.id: [] for t in tasks}
-    mon = RunMonitor(straggler_ratio=policy.straggler_ratio)
     backoffs = 0
     preempted = False
     totals = dict(global_steps=0, lane_steps=0, refills=0, n_traces=0,
@@ -258,10 +260,11 @@ def run_sweep(model: Model, tasks: Sequence[SweepTask], *,
     queue = [make_lane_task(t) for t in tasks]
     template = model.init(jax.random.PRNGKey(0))
     while queue:
-        pool = LanePool(min(pack, len(queue)), step_fn,
-                        template_params=template,
-                        template_opt=opt.init(template),
-                        template_hparams=jnp.float32(0.0))
+        with span("sweep.pool", capacity=min(pack, len(queue))):
+            pool = LanePool(min(pack, len(queue)), step_fn,
+                            template_params=template,
+                            template_opt=opt.init(template),
+                            template_hparams=jnp.float32(0.0))
         if gauges is not None:
             gauges.on_dispatch(tenant, nodes=1, lanes=pool.capacity,
                                resident_bytes=bytes_per_lane * pool.capacity)
@@ -295,9 +298,7 @@ def run_sweep(model: Model, tasks: Sequence[SweepTask], *,
             ck.wait()
 
         def on_step(global_step: int, active: int, capacity: int):
-            mon.end_step(global_step)
-            if gauges is not None:
-                gauges.on_lane_sample(tenant, gang, active, capacity)
+            gauges.on_lane_sample(tenant, gang, active, capacity)
 
         # one controller PER POOL ATTEMPT: an OOM-backoff retry gets a
         # fresh cooldown anchor and repack budget (a private gauge set —
@@ -312,14 +313,14 @@ def run_sweep(model: Model, tasks: Sequence[SweepTask], *,
 
         ex = RefillExecutor(
             pool, on_metrics=on_metrics, on_finish=on_finish,
-            on_step_start=mon.start_step, on_step=on_step,
+            on_step=on_step if gauges is not None else None,
             checkpoint_every=(policy.checkpoint_every
                               if checkpoint_dir else 0),
             on_checkpoint=on_checkpoint if checkpoint_dir else None,
             should_preempt=preempt,
             on_preempt=on_preempt if checkpoint_dir else None,
             speculative=policy.speculative_stragglers,
-            stragglers_fn=stragglers_fn or mon.stragglers,
+            stragglers_fn=stragglers_fn,
             repack_policy=controller)
         try:
             stats = ex.run(queue)

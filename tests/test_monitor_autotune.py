@@ -17,12 +17,11 @@ def test_profile_fn_counts_memory_and_flops():
     assert p.resident_bytes > 0
 
 
-def test_fits_and_load_proxy():
+def test_fits():
     p = StaticProfile(argument_bytes=10 ** 9, temp_bytes=10 ** 9,
                       output_bytes=0, flops=1e12, bytes_accessed=0)
     assert p.fits(hbm_budget=16e9)
     assert not p.fits(hbm_budget=2e9)
-    assert abs(p.load_proxy(peak_flops=2e12, step_time_s=1.0) - 0.5) < 1e-9
 
 
 def test_straggler_detection():
