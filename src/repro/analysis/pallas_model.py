@@ -116,6 +116,7 @@ class PallasCallModel:
     n_scratch: int
     scratch_exprs: Tuple[str, ...]
     dimension_semantics: Optional[Tuple[str, ...]]
+    n_prefetch: int = 0           # scalar-prefetch operands (index-map args)
 
     @property
     def key(self) -> str:
@@ -258,17 +259,24 @@ def _contains_param(node: ast.AST, params: Set[str]) -> bool:
                for n in ast.walk(node))
 
 
-def classify_index_expr(node: ast.AST, params: Set[str]) -> str:
-    """Classify one index-map output element (see module docstring)."""
+def classify_index_expr(node: ast.AST, params: Set[str],
+                        prefetch: Set[str] = frozenset()) -> str:
+    """Classify one index-map output element (see module docstring).
+    ``prefetch`` names the scalar-prefetch refs: an element of one at a
+    constant index is read once per call, a grid constant."""
     if isinstance(node, ast.Constant):
         return AFFINE if isinstance(node.value, int) else NON_AFFINE
     if isinstance(node, ast.Name):
         return AFFINE     # grid index or closure constant, both affine
+    if (isinstance(node, ast.Subscript) and isinstance(node.value, ast.Name)
+            and node.value.id in prefetch
+            and isinstance(node.slice, ast.Constant)):
+        return AFFINE
     if isinstance(node, ast.UnaryOp) and isinstance(node.op, ast.USub):
-        return classify_index_expr(node.operand, params)
+        return classify_index_expr(node.operand, params, prefetch)
     if isinstance(node, ast.BinOp):
-        lc = classify_index_expr(node.left, params)
-        rc = classify_index_expr(node.right, params)
+        lc = classify_index_expr(node.left, params, prefetch)
+        rc = classify_index_expr(node.right, params, prefetch)
         worst = max(lc, rc, key=lambda c: _CLASS_RANK[c])
         if isinstance(node.op, (ast.Add, ast.Sub)):
             return worst
@@ -289,7 +297,8 @@ def classify_index_expr(node: ast.AST, params: Set[str]) -> str:
     return NON_AFFINE
 
 
-def _model_index_map(node: ast.AST) -> Optional[IndexMapModel]:
+def _model_index_map(node: ast.AST, n_prefetch: int = 0
+                     ) -> Optional[IndexMapModel]:
     if not isinstance(node, ast.Lambda):
         return None
     params = tuple(a.arg for a in node.args.posonlyargs + node.args.args)
@@ -297,10 +306,11 @@ def _model_index_map(node: ast.AST) -> Optional[IndexMapModel]:
     elts = list(body.elts) if isinstance(body, (ast.Tuple, ast.List)) \
         else [body]
     pset = set(params)
+    prefetch = set(params[len(params) - n_prefetch:]) if n_prefetch else set()
     return IndexMapModel(
         params=params,
         exprs=tuple(ast.unparse(e) for e in elts),
-        classes=tuple(classify_index_expr(e, pset) for e in elts),
+        classes=tuple(classify_index_expr(e, pset, prefetch) for e in elts),
         lineno=node.lineno)
 
 
@@ -315,7 +325,7 @@ def _is_call_to(mod: SourceModule, node: ast.AST, canonical: str) -> bool:
 
 def _model_spec(mod: SourceModule, call: ast.Call, role: str, position: int,
                 env: _Env, nominal: Mapping[str, int],
-                conditional: bool) -> SpecModel:
+                conditional: bool, n_prefetch: int = 0) -> SpecModel:
     block_shape = resolved = None
     index_map = None
     memory_space = None
@@ -325,10 +335,10 @@ def _model_spec(mod: SourceModule, call: ast.Call, role: str, position: int,
         block_shape = tuple(ast.unparse(d) for d in dims)
         resolved = tuple(_resolve_int(d, env, nominal) for d in dims)
     if len(args) > 1:
-        index_map = _model_index_map(args[1])
+        index_map = _model_index_map(args[1], n_prefetch)
     for kw in call.keywords:
         if kw.arg == "index_map":
-            index_map = _model_index_map(kw.value)
+            index_map = _model_index_map(kw.value, n_prefetch)
         elif kw.arg == "block_shape" and isinstance(
                 kw.value, (ast.Tuple, ast.List)):
             dims = kw.value.elts
@@ -418,7 +428,18 @@ def _model_call(mod: SourceModule, fn: ast.FunctionDef, call: ast.Call,
     kernel_names = tuple(sorted(_kernel_candidates(
         mod, call.args[0], env, toplevel))) if call.args else ()
 
+    # a grid spec object (``pltpu.PrefetchScalarGridSpec``) carries the
+    # grid, the specs and the scalar-prefetch count as its own keywords
+    keywords = list(call.keywords)
+    n_prefetch = 0
     for kw in call.keywords:
+        if kw.arg == "grid_spec" and isinstance(kw.value, ast.Call):
+            keywords += kw.value.keywords
+    for kw in keywords:
+        if kw.arg == "num_scalar_prefetch":
+            n_prefetch = _resolve_int(kw.value, env, nominal) or 0
+
+    for kw in keywords:
         if kw.arg == "grid":
             gnode = kw.value
             if isinstance(gnode, ast.Name):
@@ -436,12 +457,12 @@ def _model_call(mod: SourceModule, fn: ast.FunctionDef, call: ast.Call,
             for i, (spec, cond) in enumerate(
                     _spec_nodes(mod, kw.value, env)):
                 in_specs.append(_model_spec(mod, spec, "in", i, env,
-                                            nominal, cond))
+                                            nominal, cond, n_prefetch))
         elif kw.arg == "out_specs":
             for i, (spec, cond) in enumerate(
                     _spec_nodes(mod, kw.value, env)):
                 out_specs.append(_model_spec(mod, spec, "out", i, env,
-                                             nominal, cond))
+                                             nominal, cond, n_prefetch))
         elif kw.arg == "scratch_shapes":
             snode = kw.value
             if isinstance(snode, ast.Name):
@@ -459,7 +480,8 @@ def _model_call(mod: SourceModule, fn: ast.FunctionDef, call: ast.Call,
         lineno=call.lineno, grid_rank=grid_rank, grid_exprs=grid_exprs,
         kernel_names=kernel_names, in_specs=tuple(in_specs),
         out_specs=tuple(out_specs), n_scratch=n_scratch,
-        scratch_exprs=scratch_exprs, dimension_semantics=dim_sem)
+        scratch_exprs=scratch_exprs, dimension_semantics=dim_sem,
+        n_prefetch=n_prefetch)
 
 
 def extract_pallas_calls(mod: SourceModule, nominal: Mapping[str, int]

@@ -10,8 +10,9 @@ zero) burns MXU cycles the lane predicate was supposed to save.
 
 Catalog (details in DESIGN.md §14):
 
-  PAL401  index-map arity: lambda params == grid rank, and the map's
-          output tuple arity == the BlockSpec's block-shape rank.
+  PAL401  index-map arity: lambda params == grid rank (+ scalar-
+          prefetch operands), and the map's output tuple arity == the
+          BlockSpec's block-shape rank.
   PAL402  index-map prunability: flag non-affine maps. Classification
           (affine / affine_div / non_affine) also feeds the pruning-
           readiness report (kernel_report.py) that ROADMAP 3(b)'s
@@ -67,12 +68,13 @@ def rule_pal401(modules, config):
                 if im is None:
                     continue
                 where = f"{spec.role}_specs[{spec.position}]"
-                if len(im.params) != m.grid_rank:
+                if len(im.params) != m.grid_rank + m.n_prefetch:
                     findings.append(mod.finding(
                         "PAL401", "pallas-index-map-arity", im.lineno,
                         f"`{m.entry}` {where}: index map takes "
-                        f"{len(im.params)} grid indices but the grid has "
-                        f"rank {m.grid_rank}", context=m.entry))
+                        f"{len(im.params)} arguments but the grid has "
+                        f"rank {m.grid_rank} and {m.n_prefetch} "
+                        f"scalar-prefetch operand(s)", context=m.entry))
                 if (spec.block_shape is not None
                         and len(im.exprs) != len(spec.block_shape)):
                     findings.append(mod.finding(

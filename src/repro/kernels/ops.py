@@ -147,6 +147,26 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
 
 
 # ---------------------------------------------------------------------------
+# decode attention (one token against a layer-stacked cache)
+# ---------------------------------------------------------------------------
+
+def decode_attention(q, k, v, valid, layer, impl: Optional[str] = None):
+    """One new token per sequence against layer ``layer`` of a
+    layer-stacked KV cache: q (B,1,Hq,D); k/v (L,B,Smax,Hkv*D); valid
+    (B,Smax) bool. Returns (B,1,Hq,D) in q's dtype. The kernel reads the
+    layer straight out of the stack; the XLA path slices it out
+    (``attention.sdpa_decode``)."""
+    impl = resolve_impl(impl)
+    if impl != "xla":
+        from repro.kernels.decode_attention import decode_attention_fwd
+        out = decode_attention_fwd(q[:, 0], k, v, valid, layer,
+                                   interpret=impl == "pallas_interpret")
+        return out[:, None].astype(q.dtype)
+    from repro.models.attention import sdpa_decode
+    return sdpa_decode(q, k[layer], v[layer], valid)
+
+
+# ---------------------------------------------------------------------------
 # SSD scan
 # ---------------------------------------------------------------------------
 

@@ -6,26 +6,32 @@ continuous batching (the inference-side analogue of the paper's
 concurrent-jobs-per-GPU packing, on the persistent-lane-pool model of
 core/lanepool.py):
 
-  * the decode state is a fixed-capacity pool — per-lane KV caches stacked
-    on a leading lane axis, decode compiled ONCE as a vmap over lanes;
+  * the decode state is a fixed-capacity pool: the model's own decode
+    cache at batch = lanes, layer-major (``(L, lanes, Smax, H*D)`` for
+    K/V), the layout the layer scan carries. Decode is the model's batched
+    ``decode_step``, compiled ONCE per pool width; each step writes only
+    the new token's K/V row per layer and lane into the donated pool, so
+    no step copies, transposes or re-stacks it;
   * a request joins MID-DECODE the moment a lane frees: its prompt is
-    prefilled at batch 1 and its cache swapped into the free lane via a
-    pytree index update (no recompilation, other lanes undisturbed);
+    prefilled at batch 1 and its cache written into the free lane by one
+    jitted, donated ``attach_lane`` (no recompilation, other lanes
+    undisturbed);
   * a finished lane stops burning decode budget — its request is retired
     immediately (``Request.done``) and the next queued request takes the
     lane, so total active lane-steps equal the sum of per-request
     ``max_new``, not ``capacity × max(max_new)`` (the wave-mode waste).
 
-Lanes are independent under vmap, so a request's tokens are identical
-whatever co-residents it decodes next to (prompts are left-padded to one
-fixed length per ``run``).
+Lanes are independent: every op of a decode step is per sequence (a
+routed MoE step gives every token room at every expert), so a request's
+tokens are identical whatever co-residents it decodes next to (prompts are
+left-padded to one fixed length per ``run``).
 
-``run`` is traced (core/monitor.span): ``serve.pool`` (the first prefill
-and the stacked cache pool), then per loop iteration ``serve.step``
-holding ``serve.decode`` (the step's dispatch), ``serve.wait`` (the host
-waiting for the next tokens), ``serve.readback`` (their copy to the
-host) and one ``serve.join`` per joining request (``serve.prefill``,
-``serve.attach``, then the read of its first token).
+``run`` is traced (core/monitor.span): ``serve.pool`` (the empty pool's
+allocation, with its ``cache_bytes``), then per loop iteration
+``serve.step`` holding ``serve.decode`` (the step's dispatch),
+``serve.wait`` (the host waiting for the next tokens), ``serve.readback``
+(their copy to the host) and one ``serve.join`` per joining request
+(``serve.prefill``, ``serve.attach``, then the read of its first token).
 """
 from __future__ import annotations
 
@@ -36,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import packing
 from repro.core.monitor import span
 from repro.models.model import Model
 
@@ -52,6 +57,26 @@ def make_serve_step(model: Model) -> Callable:
     def serve_step(params, batch, cache):
         return model.decode_step(params, batch, cache)
     return serve_step
+
+
+def lane_axes(model: Model, max_len: int) -> Any:
+    """The batch axis of each leaf of ``model``'s decode cache (the axis
+    whose size follows the batch), as a pytree of ints."""
+    one, two = (jax.eval_shape(lambda b=b: model.make_cache(b, max_len))
+                for b in (1, 2))
+    return jax.tree_util.tree_map(
+        lambda a, b: next(i for i, (m, n) in enumerate(zip(a.shape, b.shape))
+                          if m != n), one, two)
+
+
+def make_attach_lane(axes: Any) -> Callable:
+    """(pool, lane_cache, lane) -> pool with the batch-1 ``lane_cache``
+    written into lane ``lane`` (traced) of every leaf, on its batch axis."""
+    def attach_lane(pool, lane_cache, lane):
+        return jax.tree_util.tree_map(
+            lambda p, x, ax: jax.lax.dynamic_update_slice_in_dim(
+                p, x.astype(p.dtype), lane, axis=ax), pool, lane_cache, axes)
+    return attach_lane
 
 
 @dataclasses.dataclass
@@ -96,10 +121,10 @@ class BatchServer:
     With ``adaptive_lanes`` the pool RESIZES to queue depth between decode
     steps (the serving face of online elastic repacking, core/repack.py):
     as the request tail drains, live lanes are compacted into a smaller
-    pool so the vmapped step stops paying for dead lanes. Lane counts are
+    pool so the batched step stops paying for dead lanes. Lane counts are
     rounded to powers of two, so at most log2(batch_lanes) decode variants
-    ever compile; per-request tokens are unchanged (lanes are independent
-    under vmap).
+    ever compile; per-request tokens are unchanged (lanes are
+    independent).
     """
 
     def __init__(self, model: Model, params, batch_lanes: int, max_len: int,
@@ -111,11 +136,13 @@ class BatchServer:
         self.adaptive_lanes = adaptive_lanes
         self.stats = ServeStats()
         self._prefill = jax.jit(make_prefill(model, max_len))
-        # decode one lane at batch 1, vmapped over the lane axis of the
-        # cache pool — compiled once per run() shape set
-        self._step = jax.jit(jax.vmap(make_serve_step(model),
-                                      in_axes=(None, 0, 0)),
-                             donate_argnums=(2,))
+        # the model's batched decode over the whole pool (one sequence per
+        # lane), updating the donated pool in place
+        self._step = jax.jit(make_serve_step(model), donate_argnums=(2,))
+        self._axes = lane_axes(model, max_len)
+        self._attach = jax.jit(make_attach_lane(self._axes),
+                               donate_argnums=(0,))
+        self._empty = jax.jit(model.make_cache, static_argnums=(0, 1))
 
     def run(self, requests: List[Request]) -> Dict[int, List[int]]:
         queue = [r for r in list(requests) if r.max_new > 0]
@@ -149,56 +176,55 @@ class BatchServer:
             first = jnp.argmax(logits, -1).astype(jnp.int32)   # (1,)
             return first, cache
 
-        # seed the pool from the first prefill so every leaf has its lane
-        # axis before any swap (shapes fixed until an adaptive resize)
-        with span("serve.pool", lanes=C):
-            first0, cache0 = prefill_one(queue[0])
-            pool_cache = packing.stack_trees([cache0] * C)
-        cur = np.zeros((C, 1, 1), np.int32)          # per-lane (B=1, T=1)
-        pos = np.full((C, 1), S_pad, np.int32)
+        # the pool's shapes are fixed until an adaptive resize
+        shapes = jax.eval_shape(lambda: self.model.make_cache(C, self.max_len))
+        nbytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(shapes))
+        with span("serve.pool", lanes=C, cache_bytes=nbytes):
+            pool_cache = self._empty(C, self.max_len)
+        cur = np.zeros((C, 1), np.int32)             # per-lane token (B, T=1)
+        pos = np.full((C,), S_pad, np.int32)
         lane_req: List[Optional[Request]] = [None] * C
 
-        def attach(lane: int, r: Request, first=None, cache=None):
+        def attach(lane: int, r: Request):
             nonlocal pool_cache
             with span("serve.join", request=r.id, lane=lane):
-                if first is None:
-                    with span("serve.prefill"):
-                        first, cache = prefill_one(r)
+                with span("serve.prefill"):
+                    first, cache = prefill_one(r)
                 with span("serve.attach"):
-                    pool_cache = packing.tree_set_lane(pool_cache, lane,
-                                                       cache)
-                # the first token is read after the swap is dispatched,
-                # so that the swap queues behind the prefill on the device
-                cur[lane, 0, 0] = int(first[0])
-                pos[lane, 0] = S_pad
+                    pool_cache = self._attach(pool_cache, cache,
+                                              np.int32(lane))
+                # the first token is read after the attach is dispatched,
+                # so that it queues behind the prefill on the device
+                cur[lane, 0] = int(first[0])
+                pos[lane] = S_pad
                 lane_req[lane] = r
 
         def resize(new_c: int):
-            """Compact live lanes into a pool of ``new_c`` lanes (pure
-            pytree reads/stack — per-lane state is untouched)."""
+            """Compact live lanes into a pool of ``new_c`` lanes: a gather
+            on each leaf's lane axis (per-lane state is untouched)."""
             nonlocal pool_cache, cur, pos, lane_req, C
             live = [l for l, r in enumerate(lane_req) if r is not None]
-            caches = [packing.tree_get_lane(pool_cache, l) for l in live]
-            template = caches[0] if caches \
-                else packing.tree_get_lane(pool_cache, 0)
-            new_cache = packing.stack_trees(
-                caches + [template] * (new_c - len(caches)))
-            new_cur = np.zeros((new_c, 1, 1), np.int32)
-            new_pos = np.full((new_c, 1), S_pad, np.int32)
+            # lanes past the live ones hold copies of another lane's cache
+            # until a request attaches there; their tokens are never read
+            take = np.array((live + (live[:1] or [0]) * new_c)[:new_c],
+                            np.int32)
+            pool_cache = jax.tree_util.tree_map(
+                lambda p, ax: jnp.take(p, take, axis=ax), pool_cache,
+                self._axes)
+            new_cur = np.zeros((new_c, 1), np.int32)
+            new_pos = np.full((new_c,), S_pad, np.int32)
             new_req: List[Optional[Request]] = [None] * new_c
             for i, l in enumerate(live):
                 new_cur[i] = cur[l]
                 new_pos[i] = pos[l]
                 new_req[i] = lane_req[l]
-            pool_cache, cur, pos, lane_req, C = \
-                new_cache, new_cur, new_pos, new_req, new_c
+            cur, pos, lane_req, C = new_cur, new_pos, new_req, new_c
             self.stats.resizes += 1
             self.stats.lane_trace.append((self.stats.global_steps, new_c))
 
-        attach(0, queue.pop(0), first0, cache0)
-        for lane in range(1, C):
-            if queue:
-                attach(lane, queue.pop(0))
+        for lane in range(C):
+            attach(lane, queue.pop(0))
 
         while True:
             with span("serve.step",
@@ -212,7 +238,7 @@ class BatchServer:
                 for lane, r in enumerate(lane_req):
                     if r is None:
                         continue
-                    r.out.append(int(cur[lane, 0, 0]))
+                    r.out.append(int(cur[lane, 0]))
                     self.stats.lane_steps += 1
                     if len(r.out) >= r.max_new:
                         r.done = True    # lane frees NOW — no wave barrier
@@ -234,15 +260,15 @@ class BatchServer:
                             {"tokens": jnp.asarray(cur),
                              "pos": jnp.asarray(pos)},
                             pool_cache)
-                        nxt = jnp.argmax(logits, -1)            # (C, 1)
+                        nxt = jnp.argmax(logits, -1)            # (C,)
                     with span("serve.wait"):
                         jax.block_until_ready(nxt)
                     with span("serve.readback"):
                         nxt = np.asarray(nxt, np.int32)
                     self.stats.global_steps += 1
                     self.stats.lane_slots += C
-                    cur[active, 0, 0] = nxt[active, 0]
-                    pos[active, 0] += 1      # inactive lanes stay frozen
+                    cur[active, 0] = nxt[active]
+                    pos[active] += 1         # inactive lanes stay frozen
                 # refill phase — strictly AFTER the step: a joiner's first
                 # token (from its prefill) sits in ``cur`` and must be
                 # emitted next iteration before the lane is ever stepped;

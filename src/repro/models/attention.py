@@ -142,17 +142,61 @@ def sdpa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                 valid: jax.Array) -> jax.Array:
     """Single-token decode attention over a cache with explicit validity.
 
-    q: (B, 1, Hq, D); caches: (B, Smax, Hkv, D); valid: (B, Smax) bool.
+    q: (B, 1, Hq, D); caches: (B, Smax, Hkv*D); valid: (B, Smax) bool.
     """
     B, _, Hq, D = q.shape
-    _, Smax, Hkv, _ = k_cache.shape
+    Smax = k_cache.shape[1]
+    Hkv = k_cache.shape[2] // D
     G = Hq // Hkv
     qf = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, Hkv, G, D)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qf, k_cache.astype(jnp.float32))
+    k = k_cache.reshape(B, Smax, Hkv, D).astype(jnp.float32)
+    v = v_cache.reshape(B, Smax, Hkv, D).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bkhd->bhgk", qf, k)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgk,bkhd->bhgd", p, v_cache.astype(jnp.float32))
+    out = jnp.einsum("bhgk,bkhd->bhgd", p, v)
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
+
+
+def decode_attention(q: jax.Array, k_row: jax.Array, v_row: jax.Array,
+                     pos: jax.Array, kv_cache: dict,
+                     layer: Optional[jax.Array], window: int,
+                     impl: Optional[str] = None) -> tuple:
+    """One token's attention over its ring KV cache, written in place.
+
+    q (B,1,Hq,D); k_row/v_row (B,Hkv,D); pos (B,) absolute positions.
+    With ``layer`` given, the cache leaves are stacked over layers
+    (``(L,B,Smax,Hkv*D)``, ``len`` (L,B), ``pos`` (L,B,Smax)): only the
+    token's K/V row, its ``pos`` entry and ``len`` of that layer are
+    written, by scatters into the (donated) stack, and the layer's cache is
+    read once by the attention (``kernels.ops.decode_attention``; the
+    kernel reads it straight out of the stack). Nothing else
+    of the stack is copied. Returns (out (B,1,Hq,D), new cache in the
+    layout given).
+    """
+    stacked = layer is not None
+    if not stacked:
+        kv_cache = jax.tree_util.tree_map(lambda a: a[None], kv_cache)
+        layer = 0
+    B = q.shape[0]
+    Smax = kv_cache["k"].shape[2]
+    idx = kv_cache["len"][layer]                         # (B,) tokens so far
+    slot = idx % Smax
+    bidx = jnp.arange(B)
+    new = {"k": kv_cache["k"].at[layer, bidx, slot].set(k_row.reshape(B, -1)),
+           "v": kv_cache["v"].at[layer, bidx, slot].set(v_row.reshape(B, -1)),
+           "pos": kv_cache["pos"].at[layer, bidx, slot].set(pos),
+           "len": kv_cache["len"].at[layer].set(idx + 1)}
+    # validity from absolute positions: written, and inside the window
+    p = new["pos"][layer]                                # (B,Smax)
+    cur = pos[:, None]
+    valid = (p >= 0) & (p <= cur)
+    if window:
+        valid = valid & (p > cur - window)
+    out = kops.decode_attention(q, new["k"], new["v"], valid, layer, impl)
+    if not stacked:
+        new = jax.tree_util.tree_map(lambda a: a[0], new)
+    return out, new
 
 
 def project_kv(params: dict, ctx: jax.Array, num_kv_heads: int,
@@ -190,14 +234,18 @@ def attention_block(params: dict, x: jax.Array, *,
                     kv_cache: Optional[dict] = None,
                     impl: Optional[str] = None,
                     prob_dtype=jnp.float32,
-                    kv_ctx: Optional[jax.Array] = None) -> tuple:
+                    kv_ctx: Optional[jax.Array] = None,
+                    layer: Optional[jax.Array] = None) -> tuple:
     """Returns (out, new_kv_cache).
 
     Modes:
       * kv_cache is None, kv_ctx is None   -> self-attention over x (train/prefill)
       * kv_cache given & x is 1 token      -> cached decode step
       * kv_ctx given                       -> cross-attention onto kv_ctx
-    kv_cache = {"k": (B,Smax,Hkv,D), "v": ..., "len": (B,) int32}.
+    kv_cache = {"k": (B,Smax,Hkv*D), "v": ..., "len": (B,) int32,
+    "pos": (B,Smax) int32}. In a decode step with ``layer`` given, every
+    leaf carries a leading layer axis and the step reads and writes layer
+    ``layer`` of it (``decode_attention``).
     """
     impl = kops.resolve_impl(impl)
     B, S, _ = x.shape
@@ -223,24 +271,8 @@ def attention_block(params: dict, x: jax.Array, *,
         k = layers.apply_rope(k, positions, rope_theta)
 
     if kv_cache is not None and S == 1:  # decode step (ring write: idx % Smax)
-        Smax = kv_cache["k"].shape[1]
-        idx = kv_cache["len"]                            # (B,) tokens so far
-        slot = idx % Smax
-        bidx = jnp.arange(B)
-        k_new = kv_cache["k"].at[bidx, slot].set(k[:, 0])
-        v_new = kv_cache["v"].at[bidx, slot].set(v[:, 0])
-        pos_new = kv_cache["pos"].at[bidx, slot].set(positions[:, 0])
-        new_len = idx + 1
-        # validity from absolute positions: written, and inside the window
-        cur = positions[:, 0:1]                          # (B,1)
-        valid = kv_cache["pos"] >= 0
-        valid = valid.at[bidx, slot].set(True)
-        pos_after = pos_new
-        valid = valid & (pos_after <= cur)
-        if window:
-            valid = valid & (pos_after > cur - window)
-        out = sdpa_decode(q, k_new, v_new, valid)
-        new_cache = {"k": k_new, "v": v_new, "len": new_len, "pos": pos_new}
+        out, new_cache = decode_attention(q, k[:, 0], v[:, 0], positions[:, 0],
+                                          kv_cache, layer, window, impl)
     else:  # train / prefill
         if impl == "xla":
             out = sdpa_chunked(q, k, v, causal=causal, window=window,
@@ -255,6 +287,7 @@ def attention_block(params: dict, x: jax.Array, *,
                                  positions[:, -Smax:])
             else:
                 k_w, v_w, p_w = k, v, positions
+            k_w, v_w = (a.reshape(B, a.shape[1], -1) for a in (k_w, v_w))
             k_new = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], k_w, 0, axis=1)
             v_new = jax.lax.dynamic_update_slice_in_dim(kv_cache["v"], v_w, 0, axis=1)
             pos_new = jax.lax.dynamic_update_slice_in_dim(
@@ -271,10 +304,14 @@ def attention_block(params: dict, x: jax.Array, *,
 def init_kv_cache(batch: int, max_len: int, num_kv_heads: int, head_dim: int,
                   dtype) -> dict:
     """Ring KV cache. ``pos`` holds the absolute position stored in each
-    slot (-1 = empty); windowed caches set max_len == window."""
+    slot (-1 = empty); windowed caches set max_len == window. K and V keep
+    heads and head_dim merged in one minor axis, ``(B, Smax, Hkv*D)``: a
+    TPU lays that out as it is, unpadded, where a minor head_dim of 64
+    would be padded to the 128-wide tile (or the array stored permuted),
+    and a token's row is one contiguous write."""
     return {
-        "k": jnp.zeros((batch, max_len, num_kv_heads, head_dim), dtype),
-        "v": jnp.zeros((batch, max_len, num_kv_heads, head_dim), dtype),
+        "k": jnp.zeros((batch, max_len, num_kv_heads * head_dim), dtype),
+        "v": jnp.zeros((batch, max_len, num_kv_heads * head_dim), dtype),
         "len": jnp.zeros((batch,), jnp.int32),
         "pos": jnp.full((batch, max_len), -1, jnp.int32),
     }

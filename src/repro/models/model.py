@@ -206,10 +206,6 @@ class Model:
             }
         raise ValueError(fam)
 
-    def _split_cache_for_scan(self, cache):
-        """encdec: run_stack xs-cache must be per-layer dicts."""
-        return cache
-
     def prefill(self, params, batch, max_len: int):
         """Full-sequence forward filling a fresh cache. Returns
         (last_logits (B,V), cache)."""

@@ -177,15 +177,20 @@ def moe_routed(params: dict, x: jax.Array, m: MoEConfig, *,
 def moe_ffn(params: dict, x: jax.Array, m: MoEConfig, *,
             dense_params: Optional[dict] = None,
             oracle: bool = False,
-            ep_axis: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+            ep_axis: Optional[str] = None,
+            dropless: bool = False) -> Tuple[jax.Array, jax.Array]:
     """x (B,S,d) -> (y (B,S,d), aux loss). ``dense_params`` is the Arctic
-    parallel dense-residual FFN (cfg.moe.dense_residual)."""
+    parallel dense-residual FFN (cfg.moe.dense_residual). ``dropless``
+    gives every expert room for every token (capacity = tokens), so no
+    token's output depends on the others routed with it."""
     B, S, d = x.shape
     xt = x.reshape(B * S, d)
     if oracle:
         y, aux = moe_dense_oracle(params, xt, m)
     else:
-        y, aux = moe_routed(params, xt, m, ep_axis=ep_axis)
+        y, aux = moe_routed(params, xt, m,
+                            capacity=B * S if dropless else None,
+                            ep_axis=ep_axis)
     y = y.reshape(B, S, d)
     if "shared" in params:
         y = y + layers.mlp(params["shared"], x, "swiglu")

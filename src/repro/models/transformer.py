@@ -94,8 +94,9 @@ def init_stack(key, cfg: ModelConfig, kind: str, n: int, dtype):
 # block forward
 # ---------------------------------------------------------------------------
 
-def _ep_moe_call(p_moe, xt, cfg, pctx: ParallelCtx):
-    """Routed experts under shard_map EP (experts over the "model" axis)."""
+def _ep_moe_call(p_moe, xt, cfg, pctx: ParallelCtx, dropless: bool = False):
+    """Routed experts under shard_map EP (experts over the "model" axis).
+    ``dropless``: capacity = the shard's tokens (``moe.moe_ffn``)."""
     from jax.sharding import PartitionSpec as P
     mesh = pctx.mesh
     data_axes = pctx.batch_axes()
@@ -104,6 +105,7 @@ def _ep_moe_call(p_moe, xt, cfg, pctx: ParallelCtx):
     def body(router, wg, wu, wd, xt_l):
         prm = {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd}
         y, aux = moe.moe_routed(prm, xt_l, m, ep_axis="model",
+                                capacity=xt_l.shape[0] if dropless else None,
                                 combine_dtype=(jnp.bfloat16 if pctx.ep_bf16
                                                else None))
         aux = jax.lax.pmean(aux, data_axes)
@@ -119,23 +121,25 @@ def _ep_moe_call(p_moe, xt, cfg, pctx: ParallelCtx):
 
 def attn_block_fwd(p: dict, x, cfg: ModelConfig, *, positions,
                    mrope_positions=None, window: int, causal: bool,
-                   cache=None, pctx: ParallelCtx):
+                   cache=None, layer=None, pctx: ParallelCtx):
     hd = cfg.resolved_head_dim
     out, new_cache = attention.attention_block(
         p["attn"], layers.rms_norm(x, p["ln1"], cfg.norm_eps),
         num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads, head_dim=hd,
         positions=positions, rope_theta=cfg.rope_theta,
         mrope_positions=mrope_positions, causal=causal, window=window,
-        kv_cache=cache, impl=pctx.attn_impl,
+        kv_cache=cache, layer=layer, impl=pctx.attn_impl,
         prob_dtype=jnp.bfloat16 if pctx.score_bf16 else jnp.float32)
     return out, new_cache
 
 
 def block_fwd(p: dict, x, cfg: ModelConfig, kind: str, *, positions,
               mrope_positions=None, window: int = 0, causal: bool = True,
-              cache=None, enc_memory=None, pctx: ParallelCtx,
+              cache=None, layer=None, enc_memory=None, pctx: ParallelCtx,
               ) -> Tuple[jax.Array, Any, jax.Array]:
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux). With ``layer`` given (a decode step),
+    ``cache`` is the whole stack, every leaf with a leading layer axis,
+    and the block reads and writes its layer of it in place."""
     aux = jnp.zeros((), jnp.float32)
     self_cache = cache["self"] if (kind == "cross" and cache is not None) else cache
     if kind == "ssm":
@@ -143,9 +147,13 @@ def block_fwd(p: dict, x, cfg: ModelConfig, kind: str, *, positions,
         if cache is None:
             y, _ = ssm.mamba2_block(p["mamba"], h, cfg.d_model, cfg.ssm)
             new_cache = None
-        elif h.shape[1] == 1:  # decode
-            y, new_cache = ssm.mamba2_decode_step(
-                p["mamba"], h[:, 0], cache, cfg.d_model, cfg.ssm)
+        elif h.shape[1] == 1:  # decode: the layer's state, rewritten whole
+            y, state = ssm.mamba2_decode_step(
+                p["mamba"], h[:, 0],
+                jax.tree_util.tree_map(lambda c: c[layer], cache),
+                cfg.d_model, cfg.ssm)
+            new_cache = jax.tree_util.tree_map(
+                lambda c, n: c.at[layer].set(n), cache, state)
             y = y[:, None]
         else:  # prefill: run full seq, produce states for decode
             y, ssm_state = ssm.mamba2_block(p["mamba"], h, cfg.d_model, cfg.ssm)
@@ -159,14 +167,15 @@ def block_fwd(p: dict, x, cfg: ModelConfig, kind: str, *, positions,
     # attention blocks
     out, new_self = attn_block_fwd(
         p, x, cfg, positions=positions, mrope_positions=mrope_positions,
-        window=window, causal=causal, cache=self_cache, pctx=pctx)
+        window=window, causal=causal, cache=self_cache, layer=layer,
+        pctx=pctx)
     x = _constrain_act(x + out, pctx)
     new_cache = new_self
 
     if kind == "cross":
         hd = cfg.resolved_head_dim
         if cache is not None and enc_memory is None:      # decode: cached KV
-            ck, cv = cache["cross_k"], cache["cross_v"]
+            ck, cv = cache["cross_k"][layer], cache["cross_v"][layer]
         else:                                             # train / prefill
             ck, cv = attention.project_kv(
                 p["cross_attn"], enc_memory, cfg.num_kv_heads, hd)
@@ -174,18 +183,23 @@ def block_fwd(p: dict, x, cfg: ModelConfig, kind: str, *, positions,
             p["cross_attn"], layers.rms_norm(x, p["ln_cross"], cfg.norm_eps),
             ck, cv, cfg.num_heads, hd)
         x = _constrain_act(x + out, pctx)
-        if cache is not None:
+        if layer is not None:      # decode: the read-only cross K/V ride along
+            new_cache = dict(cache, self=new_self)
+        elif cache is not None:
             new_cache = {"self": new_self, "cross_k": ck, "cross_v": cv}
 
     h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
     if kind == "moe":
+        # a decode step (``layer`` given) routes dropless, so that no
+        # sequence's output depends on the others in its batch
         m = cfg.moe
         if pctx.moe_oracle:
             y, aux = moe.moe_ffn(p["moe"], h, m,
                                  dense_params=p.get("dense_mlp"), oracle=True)
         elif pctx.ep and pctx.mesh is not None:
             B, S, d = h.shape
-            y, aux = _ep_moe_call(p["moe"], h.reshape(B * S, d), cfg, pctx)
+            y, aux = _ep_moe_call(p["moe"], h.reshape(B * S, d), cfg, pctx,
+                                  dropless=layer is not None)
             y = y.reshape(B, S, d)
             if "shared" in p["moe"]:
                 y = y + layers.mlp(p["moe"]["shared"], h, "swiglu")
@@ -193,7 +207,8 @@ def block_fwd(p: dict, x, cfg: ModelConfig, kind: str, *, positions,
                 y = y + layers.mlp(p["dense_mlp"], h, "swiglu")
         else:
             y, aux = moe.moe_ffn(p["moe"], h, m,
-                                 dense_params=p.get("dense_mlp"), oracle=False)
+                                 dense_params=p.get("dense_mlp"), oracle=False,
+                                 dropless=layer is not None)
     else:
         y = layers.mlp(p["mlp"], h, cfg.mlp_type)
     return _constrain_act(x + y, pctx), new_cache, aux
@@ -211,7 +226,12 @@ def run_stack(params_stack, x, cfg: ModelConfig, kind: str, *, positions,
               mrope_positions=None, window: int = 0, causal: bool = True,
               caches=None, enc_memory=None, pctx: ParallelCtx):
     """Scan a homogeneous stack. caches: pytree stacked on leading L dim.
-    Returns (x, new_caches, aux_sum)."""
+    Returns (x, new_caches, aux_sum).
+
+    A decode step (one token, caches given) carries the stacked caches
+    through the scan, and each layer writes its own token's entries into
+    them in place (``block_fwd`` with ``layer``): no layer's cache is
+    sliced out as a scan input or re-stacked as a scan output."""
 
     def body(carry, inp):
         h = carry
@@ -228,6 +248,19 @@ def run_stack(params_stack, x, cfg: ModelConfig, kind: str, *, positions,
             return h, aux
         x, auxs = jax.lax.scan(_maybe_remat(body_nc, cfg), x, params_stack)
         return x, None, auxs.sum()
+    if x.shape[1] == 1:
+        def body_decode(carry, inp):
+            h, c = carry
+            p_l, layer = inp
+            h, c, aux = block_fwd(
+                p_l, h, cfg, kind, positions=positions,
+                mrope_positions=mrope_positions, window=window, causal=causal,
+                cache=c, layer=layer, enc_memory=enc_memory, pctx=pctx)
+            return (h, c), aux
+        n = jax.tree_util.tree_leaves(params_stack)[0].shape[0]
+        (x, new_caches), auxs = jax.lax.scan(
+            body_decode, (x, caches), (params_stack, jnp.arange(n)))
+        return x, new_caches, auxs.sum()
     x, (new_caches, auxs) = jax.lax.scan(
         _maybe_remat(body, cfg), x, (params_stack, caches))
     return x, new_caches, auxs.sum()
@@ -293,10 +326,29 @@ def run_hybrid(params, x, cfg: ModelConfig, *, positions, window: int = 0,
         return x, None, aux_total
 
     sb_caches = {"ssm": caches["ssm"], "attn": caches["attn"]}
-    x, (new_sb, auxs) = jax.lax.scan(
-        _maybe_remat(super_body, cfg), x, (params["blocks"], sb_caches))
+    if x.shape[1] == 1 and n_super:
+        # decode: carry the stacks and write superblock j's entries in place
+        # (the shared block's K/V row; its Mamba states, rewritten whole)
+        def super_decode(carry, inp):
+            h, c = carry
+            p_sb, j = inp
+            ssm_j = jax.tree_util.tree_map(lambda a: a[j], c["ssm"])
+            h, ssm_j, aux = run_stack(
+                p_sb, h, cfg, "ssm", positions=positions, window=window,
+                caches=ssm_j, pctx=pctx)
+            h, attn, aux2 = block_fwd(
+                shared, h, cfg, "dense", positions=positions, window=window,
+                causal=True, cache=c["attn"], layer=j, pctx=pctx)
+            ssm_c = jax.tree_util.tree_map(lambda a, n: a.at[j].set(n),
+                                           c["ssm"], ssm_j)
+            return (h, {"ssm": ssm_c, "attn": attn}), aux + aux2
+        (x, new_caches), auxs = jax.lax.scan(
+            super_decode, (x, sb_caches),
+            (params["blocks"], jnp.arange(n_super)))
+    else:
+        x, (new_caches, auxs) = jax.lax.scan(
+            _maybe_remat(super_body, cfg), x, (params["blocks"], sb_caches))
     aux_total = auxs.sum()
-    new_caches = {"ssm": new_sb["ssm"], "attn": new_sb["attn"]}
     if n_tail:
         x, new_tail, a = run_stack(params["tail"], x, cfg, "ssm",
                                    positions=positions, window=window,

@@ -45,6 +45,10 @@ PALLAS_NOMINAL_DIMS: Dict[str, Dict[str, int]] = {
     "src/repro/kernels/fused_rmsnorm.py": {"d": 1024},    # feature dim
     "src/repro/kernels/ssd_scan.py": {
         "nh": 8, "hd": 64, "N": 64},  # heads, head dim, state dim
+    # heads per block, query group, head dim, cache length, positions
+    # per grid step
+    "src/repro/kernels/decode_attention.py": {
+        "hb": 8, "G": 1, "D": 64, "S": 1024, "bs": 1024},
 }
 
 #: Expected HBM bytes streamed per grid step, keyed ``relpath::entry``,
@@ -58,6 +62,7 @@ PALLAS_TILE_BUDGETS: Dict[str, float] = {
     "src/repro/kernels/fused_rmsnorm.py::fused_rmsnorm": 2101248.0,
     "src/repro/kernels/fused_rmsnorm.py::packed_rmsnorm": 2101248.0,
     "src/repro/kernels/ssd_scan.py::ssd_scan": 148992.0,
+    "src/repro/kernels/decode_attention.py::decode_attention_fwd": 4216832.0,
 }
 
 #: Allowed relative drift between the modeled bytes/step and the budget.

@@ -474,18 +474,22 @@ def test_lint_walk_is_deterministic(tmp_path, capsys):
 REAL_PACKED_GEMM = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "..",
     "src", "repro", "kernels", "packed_gemm.py")
+REAL_DECODE_ATTENTION = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..",
+    "src", "repro", "kernels", "decode_attention.py")
 
 
-def _gemm_tree(tmp_path, mutate=None):
+def _gemm_tree(tmp_path, mutate=None, source=None):
+    source = source or REAL_PACKED_GEMM
     pkg = tmp_path / "src" / "repro" / "kernels"
     pkg.mkdir(parents=True)
-    with open(REAL_PACKED_GEMM, "r", encoding="utf-8") as f:
+    with open(source, "r", encoding="utf-8") as f:
         text = f.read()
     if mutate:
         old, new = mutate
         assert old in text, f"seed pattern {old!r} not found"
         text = text.replace(old, new, 1)
-    (pkg / "packed_gemm.py").write_text(text)
+    (pkg / os.path.basename(source)).write_text(text)
     return tmp_path
 
 
@@ -517,6 +521,20 @@ def test_cli_seeded_index_map_arity_bug_fails(tmp_path, capsys):
     assert "PAL401" in captured
 
 
+@pytest.mark.parametrize("mutate,rc", [
+    (None, 0),
+    (("lambda b, j, i, lay: (lay[0], b, i, j)",
+      "lambda b, j, i: (0, b, i, j)"),
+     1)])
+def test_cli_scalar_prefetch_index_maps(tmp_path, capsys, mutate, rc):
+    """A grid spec with scalar prefetch (decode attention's layer index):
+    its index maps take the grid indices and then the prefetched refs,
+    and one that drops the ref trips PAL401."""
+    root = _gemm_tree(tmp_path, mutate=mutate, source=REAL_DECODE_ATTENTION)
+    assert lint_cli.main(["--root", str(root), "--check"]) == rc
+    assert ("PAL401" in capsys.readouterr().out) == bool(rc)
+
+
 # -------------------------------------------------------------------------
 # kernel_report: the pruning-readiness contract
 # -------------------------------------------------------------------------
@@ -528,10 +546,11 @@ def test_kernel_report_classifies_all_committed_maps():
     from repro.analysis.kernel_report import build_report
 
     rep = build_report(default_config())
-    assert rep["n_kernels"] == 5
+    assert rep["n_kernels"] == 6
     by_entry = {k["entry"]: k for k in rep["kernels"]}
     assert set(by_entry) == {"flash_attention_fwd", "fused_rmsnorm",
-                             "packed_rmsnorm", "packed_gemm", "ssd_scan"}
+                             "packed_rmsnorm", "packed_gemm", "ssd_scan",
+                             "decode_attention_fwd"}
     for k in rep["kernels"]:
         for spec in k["operands"]:
             if spec["index_map"] is None:
@@ -595,4 +614,4 @@ def test_kernel_report_out_writes_json(tmp_path, capsys):
     stdout_payload = json.loads(capsys.readouterr().out)
     file_payload = json.loads(out.read_text())
     assert stdout_payload == file_payload
-    assert file_payload["n_kernels"] == 5
+    assert file_payload["n_kernels"] == 6

@@ -8,7 +8,10 @@ described, not attached. Nothing runs. The topology is described inside
 a module-scoped fixture (never at import), so every test worker collects
 the same tests and only the worker given this file loads libtpu.
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,11 +19,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
+from repro.kernels.decode_attention import decode_attention_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.fused_rmsnorm import packed_rmsnorm
 from repro.kernels.packed_gemm import packed_gemm
 from repro.kernels.ssd_scan import ssd_scan
-from repro.launch.serve import make_prefill
+from repro.launch.serve import make_prefill, make_serve_step
 from repro.models import ParallelCtx, build_model
 
 
@@ -98,6 +102,23 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("Hq,Hkv,D,S", [
+    (32, 32, 64, 4096),     # stablelm-1.6b at its published context
+    (12, 3, 64, 4096)])     # an odd KV head count: 192-wide rows
+def test_decode_attention_compiles(one_chip, Hq, Hkv, D, S):
+    """The decode-attention kernel over a 24-layer stack of 8 caches,
+    long enough to take several position blocks."""
+    L, B = 24, 8
+    text = _compile(one_chip,
+                    lambda q, k, v, valid, layer: decode_attention_fwd(
+                        q, k, v, valid, layer),
+                    ((B, Hq, D), jnp.bfloat16),
+                    ((L, B, S, Hkv * D), jnp.bfloat16),
+                    ((L, B, S, Hkv * D), jnp.bfloat16),
+                    ((B, S), jnp.bool_), ((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_stablelm_prefill_holds_flash_kernel(one_chip):
     """The server's prefill at published widths, with the Pallas path
     named explicitly (this host's backend is the CPU, where the default
@@ -111,3 +132,42 @@ def test_stablelm_prefill_holds_flash_kernel(one_chip):
     compiled = jax.jit(make_prefill(model, 80)).lower(
         params, {"tokens": tokens}).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("max_len", [1024, 4096])
+def test_stablelm_decode_keeps_the_pool_in_place(one_chip, max_len):
+    """The server's decode step at published widths, 8 lanes and a pool
+    of the benchmark's 1024 positions or of the model's published 4096 in
+    the benchmark's types: every op whose output is the size of a K/V pool
+    leaf is an in-place row write (a scatter, or the fusion around one),
+    and the decode-attention kernel reads the pool. A chip whose compiler
+    wants another layout for the pool than the one it stores shows a
+    pool-sized copy here."""
+    cfg = dataclasses.replace(configs.get("stablelm-1.6b"),
+                              param_dtype="float32",
+                              compute_dtype="bfloat16", remat=True)
+    model = build_model(cfg, ParallelCtx(attn_impl="pallas"))
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    lanes = 8
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = on_chip(jax.eval_shape(lambda: model.make_cache(lanes, max_len)))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((lanes, 1), jnp.int32),
+                     "pos": jax.ShapeDtypeStruct((lanes,), jnp.int32)})
+    text = jax.jit(make_serve_step(model), donate_argnums=(2,)).lower(
+        params, batch, pool).compile().as_text()
+    assert "tpu_custom_call" in text
+    leaf = pool["k"].size
+    others = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(", line)
+        if not (m and m.group(1) and math.prod(
+                int(d) for d in m.group(1).split(",")) >= leaf):
+            continue
+        op = m.group(2)
+        if op in ("parameter", "get-tuple-element", "scatter") or (
+                op == "fusion" and '/scatter"' in line):
+            continue
+        others.append(line.strip()[:160])
+    assert not others, others

@@ -95,6 +95,28 @@ def test_ep_shard_map_matches_local_single_device():
     np.testing.assert_allclose(float(a_local), float(a_ep), rtol=1e-5)
 
 
+@pytest.mark.parametrize("dropless", [False, True])
+def test_ep_call_dropless_keeps_every_token(dropless):
+    """Under EP a decode step routes dropless (capacity = the shard's
+    tokens), so each token's output is what it gets routed alone; the
+    default capacity (factor 1.25) drops some of the same tokens."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models.transformer import ParallelCtx, _ep_moe_call
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    m, p, x = _setup(cf=1.25, T=64)
+    cfg = dataclasses.replace(configs.get("deepseek-moe-16b"), moe=m)
+    y_ep, _ = _ep_moe_call(p, x, cfg, ParallelCtx(mesh=mesh, ep=True),
+                           dropless=dropless)
+    y_alone = jnp.concatenate([moe.moe_dense_oracle(p, x[i:i + 1], m)[0]
+                               for i in range(x.shape[0])])
+    same = np.allclose(np.asarray(y_ep), np.asarray(y_alone), rtol=1e-4,
+                       atol=1e-4)
+    assert same == dropless
+
+
 def test_moe_grads_flow_to_router_and_experts():
     m, p, x = _setup()
     g = jax.grad(lambda p: moe.moe_routed(p, x, m)[0].sum())(p)
