@@ -34,6 +34,7 @@ class SSMConfig:
     expand: int = 2                 # d_inner = expand * d_model
     conv_width: int = 4
     chunk_size: int = 128           # SSD chunk length
+    ngroups: int = 1                # B/C groups; head h reads group h // (nh / ngroups)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,14 +55,18 @@ class ModelConfig:
     moe_layer_period: int = 1       # every k-th layer is MoE (1 = all)
     # state-space
     ssm: Optional[SSMConfig] = None
-    # hybrid (zamba2-style): one shared attention block applied every k SSM layers
-    hybrid_attn_period: int = 0     # 0 = not hybrid
+    # hybrid (zamba2): at each id, shared block (i mod num_mem_blocks) of
+    # attention + MLP runs on concat(h, embedding), through the i-th
+    # application's own MLP adapter and linear, into that Mamba layer
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0           # rank of each application's MLP adapter
     # attention details
     rope_theta: float = 10_000.0
     mrope: bool = False             # Qwen2-VL multimodal rope (t/h/w sections)
     sliding_window: int = 0         # 0 = full attention
     # norms / activations
-    mlp_type: str = "swiglu"        # swiglu | gelu (non-gated)
+    mlp_type: str = "swiglu"        # swiglu | geglu (exact gelu) | gelu (non-gated)
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # modality frontend stub: tokens replaced by precomputed embeddings
@@ -74,6 +79,11 @@ class ModelConfig:
     remat: bool = True              # activation checkpointing per layer
     # citation provenance
     source: str = ""
+
+    def __post_init__(self):
+        # a configuration file gives the ids as a list
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
 
     # ---- derived -----------------------------------------------------------
     @property
@@ -107,17 +117,18 @@ class ModelConfig:
             return d * (n_q * hd) + 2 * d * (n_kv * hd) + (n_q * hd) * d
 
         def dense_ffn(width: int) -> int:
-            # SwiGLU: gate+up+down; non-gated: up+down
-            return (3 if self.mlp_type == "swiglu" else 2) * d * width
+            # gated (SwiGLU, GeGLU): gate+up+down; non-gated: up+down
+            return (2 if self.mlp_type == "gelu" else 3) * d * width
 
         def ssm_params() -> int:
             s = self.ssm
             d_in = s.expand * d
             nh = s.num_heads or d_in // s.head_dim
-            # in_proj(z,x,B,C,dt) + conv + A,D + norm + out_proj
-            in_p = d * (2 * d_in + 2 * s.state_dim * 1 + nh)
-            conv = (d_in + 2 * s.state_dim) * s.conv_width
-            return in_p + conv + 2 * nh + d_in + d_in * d
+            # in_proj(z,x,B,C,dt) + conv (+ bias) + A,D,dt_bias + norm
+            # + out_proj
+            in_p = d * (2 * d_in + 2 * s.state_dim * s.ngroups + nh)
+            conv = (d_in + 2 * s.state_dim * s.ngroups) * (s.conv_width + 1)
+            return in_p + conv + 3 * nh + d_in + d_in * d
 
         per_layer = 0
         n_dec = self.num_layers
@@ -138,8 +149,15 @@ class ModelConfig:
             body = n_dec * (ssm_params() + d)
         elif self.family == "hybrid":
             body = n_dec * (ssm_params() + d)
-            # one SHARED attention+ffn block (weights reused at each period)
-            body += attn_params() + dense_ffn(dff) + 2 * d
+            # the shared blocks: attention from concat(h, embedding), 2d
+            # wide, back to d; the MLP; norms over 2d and d
+            shared = (2 * d * (n_q + 2 * n_kv) * hd + n_q * hd * d
+                      + dense_ffn(dff) + 3 * d)
+            body += self.num_mem_blocks * shared
+            # each application: its MLP adapter (d -> r -> gate and up)
+            # and its d x d linear
+            r = self.adapter_rank
+            body += len(self.hybrid_layer_ids) * (r * (d + 2 * dff) + d * d)
         elif self.family == "encdec":
             enc_layer = attn_params() + dense_ffn(dff) + 2 * d
             dec_layer = 2 * attn_params() + dense_ffn(dff) + 3 * d  # self+cross
@@ -200,8 +218,8 @@ class ModelConfig:
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, state_dim=16, head_dim=16, num_heads=0, chunk_size=32)
-        if self.hybrid_attn_period:
-            kw["hybrid_attn_period"] = 2
+        if self.hybrid_layer_ids:
+            kw.update(num_layers=4, hybrid_layer_ids=(1, 3), adapter_rank=8)
         if self.sliding_window:
             kw["sliding_window"] = 32
         return dataclasses.replace(self, **kw)

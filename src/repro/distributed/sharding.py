@@ -91,8 +91,12 @@ class ShardingRules:
         core = shape
         m = re.search(r"(blocks|encoder|tail|hybrid)", path)
         if m and n >= 3:
-            # layer-stacked: 1 leading dim, or 2 for hybrid superblocks
-            n_lead = 2 if ("hybrid" in path and "blocks" in path and n >= 4) else 1
+            # layer-stacked: 1 leading dim; a hybrid run's plain Mamba
+            # layers 2 (units, layers); the shared blocks none
+            n_lead = 1
+            if "hybrid" in path:
+                n_lead = (2 if ".plain." in path
+                          else 0 if ".shared." in path else 1)
             lead = (None,) * n_lead
             core = shape[n_lead:]
 

@@ -45,12 +45,15 @@ BLOCK_ELEMS = 1 << 19       # most elements of one K or V block (bs * hb*D)
 def head_block(num_kv_heads: int, head_dim: int) -> int:
     """KV heads per grid step: the most that divide ``num_kv_heads`` and
     give a block width that is a multiple of 128 lanes and at most
-    ``BLOCK_WIDTH``; every head (the full width, always a legal block)
-    where no such count exists."""
-    fits = [h for h in range(1, num_kv_heads + 1)
-            if num_kv_heads % h == 0 and (h * head_dim) % LANES == 0
-            and h * head_dim <= max(BLOCK_WIDTH, head_dim)]
-    return max(fits) if fits else num_kv_heads
+    ``BLOCK_WIDTH``, or than the narrowest such width where that is wider
+    (4 heads of 224: 896 lanes); every head (the full width, always a
+    legal block) where no such count exists."""
+    legal = [h for h in range(1, num_kv_heads + 1)
+             if num_kv_heads % h == 0 and (h * head_dim) % LANES == 0]
+    if not legal:
+        return num_kv_heads
+    cap = max(BLOCK_WIDTH, min(legal) * head_dim)
+    return max(h for h in legal if h * head_dim <= cap)
 
 
 def seq_block(seq: int, width: int) -> int:
@@ -105,11 +108,13 @@ def _kernel(layer_ref, q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
                                         keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention_fwd(q, k, v, valid, layer, *, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def decode_attention_fwd(q, k, v, valid, layer, *, scale=None,
+                         interpret: bool = False):
     """q (B, Hq, D); k/v (L, B, S, Hkv*D); valid (B, S) bool; layer ()
     int. Returns (B, Hq, D) float32: attention of each sequence's query
-    over layer ``layer`` of its cache."""
+    over layer ``layer`` of its cache, the scores times ``scale``
+    (D ** -0.5 when None)."""
     B, Hq, D = q.shape
     L, _, S, W = k.shape
     Hkv = W // D
@@ -126,7 +131,7 @@ def decode_attention_fwd(q, k, v, valid, layer, *, interpret: bool = False):
     grid = (B, nb, S // bs)
     out = pl.pallas_call(
         functools.partial(_kernel, groups=G, heads=hb, head_dim=D,
-                          scale=D ** -0.5),
+                          scale=D ** -0.5 if scale is None else scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

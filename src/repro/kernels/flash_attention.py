@@ -158,11 +158,15 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
                         active: jax.Array | None = None,
+                        scale: float | None = None,
                         interpret: bool = False) -> jax.Array:
     """q (B,Sq,Hq,D); k/v (B,Sk,Hkv,D) -> (B,Sq,Hq,D).
 
     Sq/Sk are padded to block multiples internally; D should be a multiple
-    of 128 for MXU alignment (not enforced — smaller D still works).
+    of 128 for MXU alignment (not enforced — smaller D still works; a D
+    that is no multiple of 128, as zamba2's 224, is one block of the full
+    width, which the chip pads to the next 128 lanes inside the kernel).
+    ``scale`` multiplies the scores (1/sqrt(D) when None).
 
     ``active`` (bool/int (B,), optional): per-batch-lane predicate in
     SMEM. Inactive lanes' KV blocks skip the QK/PV dots entirely and
@@ -173,7 +177,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
 
     # layout: (B, H, S, D) for clean 2D tiles
     qt = jnp.moveaxis(q, 2, 1)

@@ -52,25 +52,29 @@ def resolve_impl(impl: Optional[str] = None) -> str:
 # flash attention (fwd kernel + recompute bwd)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_core(q, k, v, causal: bool, window: int, impl: str):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_core(q, k, v, causal: bool, window: int, impl: str,
+                          scale: Optional[float]):
     if impl != "xla":
         from repro.kernels.flash_attention import flash_attention_fwd
         return flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                   scale=scale,
                                    interpret=impl == "pallas_interpret")
     from repro.models.attention import sdpa_chunked
-    return sdpa_chunked(q, k, v, causal=causal, window=window)
+    return sdpa_chunked(q, k, v, causal=causal, window=window, scale=scale)
 
 
-def _fa_fwd(q, k, v, causal, window, impl):
-    return _flash_attention_core(q, k, v, causal, window, impl), (q, k, v)
+def _fa_fwd(q, k, v, causal, window, impl, scale):
+    return (_flash_attention_core(q, k, v, causal, window, impl, scale),
+            (q, k, v))
 
 
-def _fa_bwd(causal, window, impl, res, g):
+def _fa_bwd(causal, window, impl, scale, res, g):
     q, k, v = res
     from repro.models.attention import sdpa_chunked
     _, vjp = jax.vjp(
-        lambda q, k, v: sdpa_chunked(q, k, v, causal=causal, window=window),
+        lambda q, k, v: sdpa_chunked(q, k, v, causal=causal, window=window,
+                                     scale=scale),
         q, k, v)
     return vjp(g)
 
@@ -129,7 +133,8 @@ _flash_attention_masked_core.defvjp(_fam_fwd, _fam_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
-                    impl: Optional[str] = None, *, active=None):
+                    impl: Optional[str] = None, *, active=None,
+                    scale: Optional[float] = None):
     """Flash attention with the lane-mask contract of DESIGN.md §12:
     ``active`` (bool/int (B,), optional) treats the batch dim as lane
     axis — inactive lanes' outputs are exact zeros, active lanes are
@@ -138,10 +143,13 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
     Pallas path the predicate rides in SMEM and gates the QK/PV dots
     in-kernel (flash_attention._fwd_masked_kernel); the XLA fallback
     where-zeroes outside the dots. Both run under a custom_vjp whose
-    backward is recompute through sdpa_chunked."""
+    backward is recompute through sdpa_chunked. ``scale`` multiplies the
+    scores (head_dim ** -0.5 when None; unmasked calls only)."""
     impl = resolve_impl(impl)
     if active is None:
-        return _flash_attention_core(q, k, v, causal, window, impl)
+        return _flash_attention_core(q, k, v, causal, window, impl, scale)
+    if scale is not None:
+        raise NotImplementedError("a lane-masked flash call takes no scale")
     act = jnp.asarray(active, jnp.int32)
     return _flash_attention_masked_core(q, k, v, act, causal, window, impl)
 
@@ -150,20 +158,21 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
 # decode attention (one token against a layer-stacked cache)
 # ---------------------------------------------------------------------------
 
-def decode_attention(q, k, v, valid, layer, impl: Optional[str] = None):
+def decode_attention(q, k, v, valid, layer, impl: Optional[str] = None, *,
+                     scale: Optional[float] = None):
     """One new token per sequence against layer ``layer`` of a
     layer-stacked KV cache: q (B,1,Hq,D); k/v (L,B,Smax,Hkv*D); valid
     (B,Smax) bool. Returns (B,1,Hq,D) in q's dtype. The kernel reads the
     layer straight out of the stack; the XLA path slices it out
-    (``attention.sdpa_decode``)."""
+    (``attention.sdpa_decode``). ``scale`` as ``flash_attention``'s."""
     impl = resolve_impl(impl)
     if impl != "xla":
         from repro.kernels.decode_attention import decode_attention_fwd
-        out = decode_attention_fwd(q[:, 0], k, v, valid, layer,
+        out = decode_attention_fwd(q[:, 0], k, v, valid, layer, scale=scale,
                                    interpret=impl == "pallas_interpret")
         return out[:, None].astype(q.dtype)
     from repro.models.attention import sdpa_decode
-    return sdpa_decode(q, k[layer], v[layer], valid)
+    return sdpa_decode(q, k[layer], v[layer], valid, scale)
 
 
 # ---------------------------------------------------------------------------

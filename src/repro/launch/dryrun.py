@@ -34,7 +34,8 @@ from repro.models.model import Model
 from repro.models.transformer import ParallelCtx
 from repro.roofline.analysis import HW, analyze_compiled
 
-# zamba2's shared attention runs a 4096 sliding window at 500k (DESIGN.md)
+# zamba2's shared blocks attend over a 4096 sliding window at 500k: the
+# model's published context
 LONG_WINDOW = {"zamba2-7b": 4096}
 
 

@@ -26,12 +26,19 @@ routed MoE step gives every token room at every expert), so a request's
 tokens are identical whatever co-residents it decodes next to (prompts are
 left-padded to one fixed length per ``run``).
 
+A pool may hold two kinds of state side by side (zamba2): each Mamba
+layer's recurrent state (``conv`` and ``ssm`` leaves, rewritten whole by
+every step) beside the attention layers' K/V (one row written a step).
+
 ``run`` is traced (core/monitor.span): ``serve.pool`` (the empty pool's
-allocation, with its ``cache_bytes``), then per loop iteration
-``serve.step`` holding ``serve.decode`` (the step's dispatch),
-``serve.wait`` (the host waiting for the next tokens), ``serve.readback``
-(their copy to the host) and one ``serve.join`` per joining request
-(``serve.prefill``, ``serve.attach``, then the read of its first token).
+allocation, with its ``cache_bytes`` and ``state_bytes``, the recurrent
+state's share of them), then per loop iteration ``serve.step`` holding
+``serve.decode`` (the step's dispatch, with its live ``lanes`` and
+``kv_positions``: the sum over them of the cache positions each one's
+attention reads, 0 for a model with no attention), ``serve.wait`` (the host waiting for the
+next tokens), ``serve.readback`` (their copy to the host) and one
+``serve.join`` per joining request (``serve.prefill``, ``serve.attach``,
+then the read of its first token).
 """
 from __future__ import annotations
 
@@ -67,6 +74,21 @@ def lane_axes(model: Model, max_len: int) -> Any:
     return jax.tree_util.tree_map(
         lambda a, b: next(i for i, (m, n) in enumerate(zip(a.shape, b.shape))
                           if m != n), one, two)
+
+
+#: the leaves of a decode cache that hold recurrent (SSM) state
+STATE_LEAVES = ("conv", "ssm")
+
+
+def cache_bytes(shapes: Any) -> Tuple[int, int]:
+    """(all bytes, recurrent-state bytes) of a decode cache's shapes."""
+    total = state = 0
+    for path, x in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+        n = x.size * x.dtype.itemsize
+        total += n
+        if getattr(path[-1], "key", None) in STATE_LEAVES:
+            state += n
+    return total, state
 
 
 def make_attach_lane(axes: Any) -> Callable:
@@ -178,10 +200,15 @@ class BatchServer:
 
         # the pool's shapes are fixed until an adaptive resize
         shapes = jax.eval_shape(lambda: self.model.make_cache(C, self.max_len))
-        nbytes = sum(x.size * x.dtype.itemsize
-                     for x in jax.tree_util.tree_leaves(shapes))
-        with span("serve.pool", lanes=C, cache_bytes=nbytes):
+        nbytes, state_bytes = cache_bytes(shapes)
+        with span("serve.pool", lanes=C, cache_bytes=nbytes,
+                  state_bytes=state_bytes):
             pool_cache = self._empty(C, self.max_len)
+        # positions one lane's attention reads at position p: p + 1, up
+        # to the cache's (or the window's) length
+        cfg, window = self.model.cfg, self.model.window
+        attn_len = 0 if cfg.is_attention_free else (
+            min(self.max_len, window) if window else self.max_len)
         cur = np.zeros((C, 1), np.int32)             # per-lane token (B, T=1)
         pos = np.full((C,), S_pad, np.int32)
         lane_req: List[Optional[Request]] = [None] * C
@@ -254,7 +281,9 @@ class BatchServer:
                         resize(desired)
                 if n_live:
                     active = np.array([r is not None for r in lane_req])
-                    with span("serve.decode"):
+                    with span("serve.decode", lanes=n_live,
+                              kv_positions=int(np.minimum(
+                                  pos[active] + 1, attn_len).sum())):
                         logits, pool_cache = self._step(
                             self.params,
                             {"tokens": jnp.asarray(cur),

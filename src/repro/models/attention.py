@@ -28,13 +28,16 @@ from repro.models import layers
 # ---------------------------------------------------------------------------
 
 def init_attention(key, d_model: int, num_heads: int, num_kv_heads: int,
-                   head_dim: int, dtype) -> dict:
+                   head_dim: int, dtype, d_out: Optional[int] = None) -> dict:
+    """q/k/v from ``d_model``-wide inputs; the output ``d_out`` wide
+    (``d_model`` when not given)."""
     ks = jax.random.split(key, 4)
     return {
         "w_q": layers.dense_init(ks[0], d_model, num_heads * head_dim, dtype),
         "w_k": layers.dense_init(ks[1], d_model, num_kv_heads * head_dim, dtype),
         "w_v": layers.dense_init(ks[2], d_model, num_kv_heads * head_dim, dtype),
-        "w_o": layers.dense_init(ks[3], num_heads * head_dim, d_model, dtype),
+        "w_o": layers.dense_init(ks[3], num_heads * head_dim,
+                                 d_out or d_model, dtype),
     }
 
 
@@ -61,12 +64,14 @@ def sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  q_offset: int = 0,
                  chunk_k: int = 1024,
                  kv_valid_len: Optional[jax.Array] = None,
-                 prob_dtype=jnp.float32) -> jax.Array:
+                 prob_dtype=jnp.float32,
+                 scale: Optional[float] = None) -> jax.Array:
     """Online-softmax attention, scanning KV chunks.
 
     q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D); Hq % Hkv == 0.
     q_offset: absolute position of q[0] (prefill continuation / decode).
     kv_valid_len: optional (B,) number of valid cache entries.
+    scale: the scores' factor (D ** -0.5 when None).
     Returns (B, Sq, Hq, D) in q.dtype.
     """
     B, Sq, Hq, D = q.shape
@@ -79,11 +84,11 @@ def sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return _sdpa_chunked_tagged(q, k, v, causal=causal, window=window,
                                     q_offset=q_offset, chunk_k=chunk_k,
                                     kv_valid_len=kv_valid_len,
-                                    prob_dtype=prob_dtype)
+                                    prob_dtype=prob_dtype, scale=scale)
 
 
 def _sdpa_chunked_tagged(q, k, v, *, causal, window, q_offset, chunk_k,
-                         kv_valid_len, prob_dtype):
+                         kv_valid_len, prob_dtype, scale):
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     G = Hq // Hkv
@@ -94,7 +99,8 @@ def _sdpa_chunked_tagged(q, k, v, *, causal, window, q_offset, chunk_k,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     n_chunks = (Sk + pad) // chunk_k
 
-    qf = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, Sq, Hkv, G, D)
+    scale = D ** -0.5 if scale is None else scale
+    qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, Hkv, G, D)
     q_pos = q_offset + jnp.arange(Sq)
 
     kc = k.reshape(B, n_chunks, chunk_k, Hkv, D)
@@ -139,7 +145,7 @@ def _sdpa_chunked_tagged(q, k, v, *, causal, window, q_offset, chunk_k,
 
 
 def sdpa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                valid: jax.Array) -> jax.Array:
+                valid: jax.Array, scale: Optional[float] = None) -> jax.Array:
     """Single-token decode attention over a cache with explicit validity.
 
     q: (B, 1, Hq, D); caches: (B, Smax, Hkv*D); valid: (B, Smax) bool.
@@ -148,7 +154,8 @@ def sdpa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     Smax = k_cache.shape[1]
     Hkv = k_cache.shape[2] // D
     G = Hq // Hkv
-    qf = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, Hkv, G, D)
+    scale = D ** -0.5 if scale is None else scale
+    qf = (q.astype(jnp.float32) * scale).reshape(B, Hkv, G, D)
     k = k_cache.reshape(B, Smax, Hkv, D).astype(jnp.float32)
     v = v_cache.reshape(B, Smax, Hkv, D).astype(jnp.float32)
     s = jnp.einsum("bhgd,bkhd->bhgk", qf, k)
@@ -161,7 +168,8 @@ def sdpa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 def decode_attention(q: jax.Array, k_row: jax.Array, v_row: jax.Array,
                      pos: jax.Array, kv_cache: dict,
                      layer: Optional[jax.Array], window: int,
-                     impl: Optional[str] = None) -> tuple:
+                     impl: Optional[str] = None,
+                     scale: Optional[float] = None) -> tuple:
     """One token's attention over its ring KV cache, written in place.
 
     q (B,1,Hq,D); k_row/v_row (B,Hkv,D); pos (B,) absolute positions.
@@ -193,7 +201,8 @@ def decode_attention(q: jax.Array, k_row: jax.Array, v_row: jax.Array,
     valid = (p >= 0) & (p <= cur)
     if window:
         valid = valid & (p > cur - window)
-    out = kops.decode_attention(q, new["k"], new["v"], valid, layer, impl)
+    out = kops.decode_attention(q, new["k"], new["v"], valid, layer, impl,
+                                scale=scale)
     if not stacked:
         new = jax.tree_util.tree_map(lambda a: a[0], new)
     return out, new
@@ -235,8 +244,10 @@ def attention_block(params: dict, x: jax.Array, *,
                     impl: Optional[str] = None,
                     prob_dtype=jnp.float32,
                     kv_ctx: Optional[jax.Array] = None,
-                    layer: Optional[jax.Array] = None) -> tuple:
-    """Returns (out, new_kv_cache).
+                    layer: Optional[jax.Array] = None,
+                    scale: Optional[float] = None) -> tuple:
+    """Returns (out, new_kv_cache). ``scale`` multiplies the scores
+    (head_dim ** -0.5 when None).
 
     Modes:
       * kv_cache is None, kv_ctx is None   -> self-attention over x (train/prefill)
@@ -272,14 +283,15 @@ def attention_block(params: dict, x: jax.Array, *,
 
     if kv_cache is not None and S == 1:  # decode step (ring write: idx % Smax)
         out, new_cache = decode_attention(q, k[:, 0], v[:, 0], positions[:, 0],
-                                          kv_cache, layer, window, impl)
+                                          kv_cache, layer, window, impl,
+                                          scale=scale)
     else:  # train / prefill
         if impl == "xla":
             out = sdpa_chunked(q, k, v, causal=causal, window=window,
-                               prob_dtype=prob_dtype)
+                               prob_dtype=prob_dtype, scale=scale)
         else:
             out = kops.flash_attention(q, k, v, causal=causal,
-                                       window=window, impl=impl)
+                                       window=window, impl=impl, scale=scale)
         if kv_cache is not None:  # prefill into cache (keep last Smax if S>Smax)
             Smax = kv_cache["k"].shape[1]
             if S >= Smax:

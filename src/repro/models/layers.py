@@ -9,6 +9,7 @@ Conventions:
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -107,7 +108,7 @@ def apply_mrope(x: jax.Array, positions_thw: jax.Array, theta: float) -> jax.Arr
 
 def init_mlp(key, d_model: int, d_ff: int, mlp_type: str, dtype) -> dict:
     ks = jax.random.split(key, 3)
-    if mlp_type == "swiglu":
+    if mlp_type in ("swiglu", "geglu"):
         return {
             "w_gate": dense_init(ks[0], d_model, d_ff, dtype),
             "w_up": dense_init(ks[1], d_model, d_ff, dtype),
@@ -119,12 +120,31 @@ def init_mlp(key, d_model: int, d_ff: int, mlp_type: str, dtype) -> dict:
     }
 
 
-def mlp(params: dict, x: jax.Array, mlp_type: str) -> jax.Array:
+def init_adapter(key, d_model: int, d_ff: int, rank: int, dtype) -> dict:
+    """A rank-``rank`` additive adapter on a gated MLP's gate and up
+    projections: ``x @ a`` then ``@ b_gate`` and ``@ b_up``."""
+    ks = jax.random.split(key, 3)
+    return {"a": dense_init(ks[0], d_model, rank, dtype),
+            "b_gate": dense_init(ks[1], rank, d_ff, dtype),
+            "b_up": dense_init(ks[2], rank, d_ff, dtype)}
+
+
+def mlp(params: dict, x: jax.Array, mlp_type: str,
+        adapter: Optional[dict] = None) -> jax.Array:
+    """SwiGLU, GeGLU (the exact, erf GELU) or a plain GELU MLP. A gated
+    MLP's gate and up projections each add ``adapter``'s low-rank term
+    when one is given."""
     cdt = x.dtype
-    if mlp_type == "swiglu":
+    if mlp_type in ("swiglu", "geglu"):
         g = x @ params["w_gate"].astype(cdt)
         u = x @ params["w_up"].astype(cdt)
-        h = jax.nn.silu(g) * u
+        if adapter is not None:
+            r = x @ adapter["a"].astype(cdt)
+            g = g + r @ adapter["b_gate"].astype(cdt)
+            u = u + r @ adapter["b_up"].astype(cdt)
+        act = (jax.nn.silu if mlp_type == "swiglu"
+               else functools.partial(jax.nn.gelu, approximate=False))
+        h = act(g) * u
     else:
         h = jax.nn.gelu(x @ params["w_up"].astype(cdt))
     return h @ params["w_down"].astype(cdt)

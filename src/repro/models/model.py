@@ -191,8 +191,15 @@ class Model:
         if fam == "ssm":
             return ssm_stack((cfg.num_layers,))
         if fam == "hybrid":
-            n_super, period, n_tail = transformer.hybrid_layout(cfg)
-            c = {"ssm": ssm_stack((n_super, period)), "attn": kv_stack(n_super)}
+            runs, n_tail = transformer.hybrid_layout(cfg)
+
+            def segment(count, n_plain):
+                c = {"layer": ssm_stack((count,)), "kv": kv_stack(count)}
+                if n_plain:
+                    c["plain"] = ssm_stack((count, n_plain))
+                return c
+            c = {"runs": [[segment(count, n) for n in plain]
+                          for count, plain in runs]}
             if n_tail:
                 c["tail"] = ssm_stack((n_tail,))
             return c

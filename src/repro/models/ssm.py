@@ -7,7 +7,8 @@ chunks a small recurrent state (B, nh, hd, N) is carried by lax.scan.
 kernel in kernels/ssd_scan.py). Decode uses the recurrent step directly.
 
 Conventions: x (B,S,nh,hd); dt (B,S,nh); A (nh,) negative reals;
-B/C (B,S,N) shared across heads (ngroups=1, as in mamba2-130m).
+B/C (B,S,N) shared across heads (ngroups=1, as in mamba2-130m), or
+(B,S,G,N) in G groups, head h reading group h // (nh/G) (zamba2-7b).
 """
 from __future__ import annotations
 
@@ -29,10 +30,31 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array,
                 B: jax.Array, C: jax.Array, *, chunk: int,
                 init_state: Optional[jax.Array] = None,
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (y (B,S,nh,hd), final_state (B,nh,hd,N))."""
+    """Returns (y (B,S,nh,hd), final_state (B,nh,hd,N)). B/C in groups
+    (B,S,G,N): each group's heads are scanned as their own problem."""
+    if B.ndim == 4:
+        return _ssd_grouped(x, dt, A, B, C, chunk=chunk,
+                            init_state=init_state)
     with jax.named_scope("ssd"):
         return _ssd_chunked_tagged(x, dt, A, B, C, chunk=chunk,
                                    init_state=init_state)
+
+
+def _ssd_grouped(x, dt, A, B, C, *, chunk, init_state):
+    """``ssd_chunked`` mapped over the B/C groups: heads split into G
+    consecutive runs of nh/G, run g reading B[:, :, g] and C[:, :, g]."""
+    b, S, nh, hd = x.shape
+    G, N = B.shape[2:]
+    hg = nh // G
+    s0 = (None if init_state is None
+          else init_state.reshape(b, G, hg, hd, N))
+    one = lambda x_, dt_, A_, B_, C_, s_: ssd_chunked(
+        x_, dt_, A_, B_, C_, chunk=chunk, init_state=s_)
+    y, state = jax.vmap(one, in_axes=(2, 2, 0, 2, 2, None if s0 is None else 1),
+                        out_axes=(2, 1))(
+        x.reshape(b, S, G, hg, hd), dt.reshape(b, S, G, hg),
+        A.reshape(G, hg), B, C, s0)
+    return y.reshape(b, S, nh, hd), state.reshape(b, nh, hd, N)
 
 
 def _ssd_chunked_tagged(x, dt, A, B, C, *, chunk, init_state=None):
@@ -95,13 +117,18 @@ def ssd_decode_step(state: jax.Array, x_t: jax.Array, dt_t: jax.Array,
                     A: jax.Array, B_t: jax.Array, C_t: jax.Array,
                     ) -> Tuple[jax.Array, jax.Array]:
     """One recurrent step. state (B,nh,hd,N); x_t (B,nh,hd); dt_t (B,nh);
-    B_t/C_t (B,N). Returns (y_t (B,nh,hd), new_state)."""
+    B_t/C_t (B,N), or (B,G,N) in groups. Returns (y_t (B,nh,hd),
+    new_state)."""
     f32 = jnp.float32
     a = jnp.exp(dt_t.astype(f32) * A.astype(f32))          # (B,nh)
     xb = x_t.astype(f32) * dt_t.astype(f32)[..., None]     # (B,nh,hd)
-    upd = xb[..., None] * B_t.astype(f32)[:, None, None, :]
-    new_state = state * a[:, :, None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", new_state, C_t.astype(f32))
+    if B_t.ndim == 2:      # one group
+        B_t, C_t = B_t[:, None], C_t[:, None]
+    rep = x_t.shape[1] // B_t.shape[1]    # each head reads its group's B, C
+    B_h = jnp.repeat(B_t.astype(f32), rep, axis=1)         # (B,nh,N)
+    C_h = jnp.repeat(C_t.astype(f32), rep, axis=1)
+    new_state = state * a[:, :, None, None] + xb[..., None] * B_h[:, :, None]
+    y = jnp.einsum("bhpn,bhn->bhp", new_state, C_h)
     return y.astype(x_t.dtype), new_state
 
 
@@ -133,7 +160,7 @@ def causal_conv1d_step(conv_state: jax.Array, x_t: jax.Array,
 def dims(d_model: int, s: SSMConfig):
     d_in = s.expand * d_model
     nh = s.num_heads or d_in // s.head_dim
-    ch = d_in + 2 * s.state_dim      # conv channels: x_ssm + B + C
+    ch = d_in + 2 * s.ngroups * s.state_dim   # conv channels: x_ssm + B + C
     return d_in, nh, ch
 
 
@@ -166,15 +193,34 @@ def _project(params, x, d_model, s: SSMConfig):
     return z, xBC, dt_raw, (d_in, nh, ch)
 
 
+def _split_xBC(xBC, d_in: int, s: SSMConfig):
+    """x (…, d_in) and B, C: (…, N) for one group, else (…, G, N)."""
+    gn = s.ngroups * s.state_dim
+    xs, Bm, Cm = xBC[..., :d_in], xBC[..., d_in:d_in + gn], xBC[..., d_in + gn:]
+    if s.ngroups > 1:
+        Bm = Bm.reshape(*Bm.shape[:-1], s.ngroups, s.state_dim)
+        Cm = Cm.reshape(*Cm.shape[:-1], s.ngroups, s.state_dim)
+    return xs, Bm, Cm
+
+
+def gated_norm(y, z, weight, ngroups: int, eps: float = 1e-5):
+    """RMSNorm of ``y * silu(z)``, over each of ``ngroups`` equal groups of
+    channels, times ``weight``."""
+    g = y * jax.nn.silu(z)
+    if ngroups == 1:
+        return layers.rms_norm(g, weight, eps)
+    g32 = g.astype(jnp.float32).reshape(*g.shape[:-1], ngroups, -1)
+    g32 = g32 * jax.lax.rsqrt(jnp.mean(g32 * g32, -1, keepdims=True) + eps)
+    return (g32.reshape(g.shape) * weight.astype(jnp.float32)).astype(y.dtype)
+
+
 def mamba2_block(params: dict, x: jax.Array, d_model: int, s: SSMConfig,
                  init_state: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence Mamba2. x (B,S,d). Returns (y, final_ssm_state)."""
     z, xBC, dt_raw, (d_in, nh, ch) = _project(params, x, d_model, s)
     xBC = jax.nn.silu(causal_conv1d(xBC, params["conv_w"].astype(x.dtype),
                                     params["conv_b"].astype(x.dtype)))
-    xs = xBC[..., :d_in]
-    Bm = xBC[..., d_in:d_in + s.state_dim]
-    Cm = xBC[..., d_in + s.state_dim:]
+    xs, Bm, Cm = _split_xBC(xBC, d_in, s)
     b, S, _ = x.shape
     xh = xs.reshape(b, S, nh, s.head_dim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
@@ -183,7 +229,7 @@ def mamba2_block(params: dict, x: jax.Array, d_model: int, s: SSMConfig,
                            init_state=init_state)
     y = y + params["D"].astype(x.dtype)[None, None, :, None] * xh
     y = y.reshape(b, S, d_in)
-    y = layers.rms_norm(y * jax.nn.silu(z), params["norm_w"])
+    y = gated_norm(y, z, params["norm_w"], s.ngroups)
     return y @ params["w_out"].astype(x.dtype), state
 
 
@@ -195,16 +241,14 @@ def mamba2_decode_step(params: dict, x_t: jax.Array, state: dict,
         state["conv"], xBC, params["conv_w"].astype(x_t.dtype),
         params["conv_b"].astype(x_t.dtype))
     xBC = jax.nn.silu(xBC)
-    xs = xBC[..., :d_in]
-    Bm = xBC[..., d_in:d_in + s.state_dim]
-    Cm = xBC[..., d_in + s.state_dim:]
+    xs, Bm, Cm = _split_xBC(xBC, d_in, s)
     xh = xs.reshape(-1, nh, s.head_dim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
     y, ssm_state = ssd_decode_step(state["ssm"], xh, dt, A, Bm, Cm)
     y = y + params["D"].astype(x_t.dtype)[None, :, None] * xh
     y = y.reshape(-1, d_in)
-    y = layers.rms_norm(y * jax.nn.silu(z), params["norm_w"])
+    y = gated_norm(y, z, params["norm_w"], s.ngroups)
     return y @ params["w_out"].astype(x_t.dtype), {"conv": conv_state, "ssm": ssm_state}
 
 
@@ -225,7 +269,7 @@ def ssd_reference_recurrent(x, dt, A, B, C):
         y, new = ssd_decode_step(state, x_t, dt_t, A, B_t, C_t)
         return new, y
 
-    s0 = jnp.zeros((b, nh, hd, B.shape[-1]), jnp.float32)
+    s0 = jnp.zeros((b, nh, hd, B.shape[-1]), jnp.float32)   # B (b,S,[G,]N)
     xs = (jnp.moveaxis(x, 1, 0), jnp.moveaxis(dt, 1, 0),
           jnp.moveaxis(B, 1, 0), jnp.moveaxis(C, 1, 0))
     final, ys = jax.lax.scan(step, s0, xs)

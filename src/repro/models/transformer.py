@@ -1,5 +1,6 @@
-"""Transformer stacks: decoder-only LM, encoder-decoder, and the zamba2-style
-hybrid (Mamba2 backbone + one SHARED attention block applied periodically).
+"""Transformer stacks: decoder-only LM, encoder-decoder, and the zamba2
+hybrid (a Mamba2 backbone into which shared attention blocks feed at the
+configuration's ``hybrid_layer_ids``).
 
 Layers are scanned (``jax.lax.scan`` over stacked params) so the lowered HLO
 is one layer body regardless of depth — essential for dry-run compile times
@@ -136,14 +137,17 @@ def attn_block_fwd(p: dict, x, cfg: ModelConfig, *, positions,
 def block_fwd(p: dict, x, cfg: ModelConfig, kind: str, *, positions,
               mrope_positions=None, window: int = 0, causal: bool = True,
               cache=None, layer=None, enc_memory=None, pctx: ParallelCtx,
-              ) -> Tuple[jax.Array, Any, jax.Array]:
+              inject=None) -> Tuple[jax.Array, Any, jax.Array]:
     """Returns (x, new_cache, aux). With ``layer`` given (a decode step),
     ``cache`` is the whole stack, every leaf with a leading layer axis,
-    and the block reads and writes its layer of it in place."""
+    and the block reads and writes its layer of it in place. ``inject``
+    (a hybrid layer's shared-block output) is added to an SSM block's
+    input, not to its residual: ``x + mamba(norm(x + inject))``."""
     aux = jnp.zeros((), jnp.float32)
     self_cache = cache["self"] if (kind == "cross" and cache is not None) else cache
     if kind == "ssm":
-        h = layers.rms_norm(x, p["ln"], cfg.norm_eps)
+        h = layers.rms_norm(x if inject is None else x + inject, p["ln"],
+                            cfg.norm_eps)
         if cache is None:
             y, _ = ssm.mamba2_block(p["mamba"], h, cfg.d_model, cfg.ssm)
             new_cache = None
@@ -267,92 +271,194 @@ def run_stack(params_stack, x, cfg: ModelConfig, kind: str, *, positions,
 
 
 # ---------------------------------------------------------------------------
-# hybrid (zamba2): scan over superblocks of (period × mamba) + shared attn
+# hybrid (zamba2): shared blocks feeding Mamba layers at hybrid_layer_ids
 # ---------------------------------------------------------------------------
 
-def hybrid_layout(cfg: ModelConfig) -> Tuple[int, int, int]:
-    """(n_super, period, n_tail): num_layers = n_super*period + n_tail."""
-    period = cfg.hybrid_attn_period
-    n_super = cfg.num_layers // period
-    return n_super, period, cfg.num_layers - n_super * period
+def hybrid_layout(cfg: ModelConfig) -> Tuple[tuple, int]:
+    """(runs, n_tail). Each hybrid layer id closes a segment: the plain
+    Mamba layers since the previous one, then the hybrid layer, where
+    application i (in order) runs shared block i mod ``num_mem_blocks``.
+    A unit is ``num_mem_blocks`` consecutive segments (blocks 0, 1, ...;
+    the last unit may be shorter); consecutive units with the same plain
+    counts form a run ``(count, plain)``, scanned over its units.
+    ``n_tail`` plain Mamba layers follow the last hybrid layer.
+
+    Zamba2-7B's ids (6, 11, 17, ..., 77) give the runs (1, (6, 4)),
+    (5, (5, 5)), (1, (5,)) and a tail of 3."""
+    ids = cfg.hybrid_layer_ids
+    m = cfg.num_mem_blocks
+    plain = [i - p - 1 for p, i in zip((-1,) + ids[:-1], ids)]
+    runs: list = []
+    for k in range(0, len(plain), m):
+        unit = tuple(plain[k:k + m])
+        if runs and runs[-1][1] == unit:
+            runs[-1] = (runs[-1][0] + 1, unit)
+        else:
+            runs.append((1, unit))
+    return tuple(runs), cfg.num_layers - 1 - ids[-1]
+
+
+def init_shared_block(key, cfg: ModelConfig, dtype) -> dict:
+    """A shared block: norm over concat(h, embedding), attention from
+    that 2d-wide input back to d, norm, gated MLP."""
+    d = cfg.d_model
+    ks = jax.random.split(key, 2)
+    return {"ln_in": jnp.ones((2 * d,), dtype),
+            "attn": attention.init_attention(
+                ks[0], 2 * d, cfg.num_heads, cfg.num_kv_heads,
+                cfg.resolved_head_dim, dtype, d_out=d),
+            "ln_ff": jnp.ones((d,), dtype),
+            "mlp": layers.init_mlp(ks[1], d, cfg.d_ff, cfg.mlp_type, dtype)}
+
+
+def _init_segment(key, cfg: ModelConfig, count: int, n_plain: int, dtype):
+    """One segment position of a run, stacked over the run's ``count``
+    units: its plain Mamba layers (count, n_plain, ...), the hybrid
+    layer's Mamba block, the application's adapter and linear."""
+    ks = jax.random.split(key, 4)
+    d = cfg.d_model
+    seg = {"layer": init_stack(ks[0], cfg, "ssm", count, dtype),
+           "adapter": jax.vmap(lambda k: layers.init_adapter(
+               k, d, cfg.d_ff, cfg.adapter_rank, dtype))(
+                   jax.random.split(ks[1], count)),
+           "linear": jax.vmap(lambda k: layers.dense_init(k, d, d, dtype))(
+               jax.random.split(ks[2], count))}
+    if n_plain:
+        seg["plain"] = jax.tree_util.tree_map(
+            lambda a: a.reshape(count, n_plain, *a.shape[1:]),
+            init_stack(ks[3], cfg, "ssm", count * n_plain, dtype))
+    return seg
 
 
 def init_hybrid(key, cfg: ModelConfig, dtype) -> dict:
-    n_super, period, n_tail = hybrid_layout(cfg)
-    k1, k2, k3 = jax.random.split(key, 3)
-    scanned = init_stack(k1, cfg, "ssm", n_super * period, dtype)
-    scanned = jax.tree_util.tree_map(
-        lambda a: a.reshape(n_super, period, *a.shape[1:]), scanned)
-    p = {"blocks": scanned,
-         "shared": init_block(k2, cfg, "dense", dtype)}
+    """{"shared": [block per num_mem_blocks], "runs": [[segment per
+    position of the run's unit]], "tail": plain Mamba stack}."""
+    runs, n_tail = hybrid_layout(cfg)
+    k_shared, k_runs, k_tail = jax.random.split(key, 3)
+    p = {"shared": [init_shared_block(k, cfg, dtype) for k in
+                    jax.random.split(k_shared, cfg.num_mem_blocks)],
+         "runs": [[_init_segment(jax.random.fold_in(
+                                     jax.random.fold_in(k_runs, r), s),
+                                 cfg, count, n, dtype)
+                   for s, n in enumerate(plain)]
+                  for r, (count, plain) in enumerate(runs)]}
     if n_tail:
-        p["tail"] = init_stack(k3, cfg, "ssm", n_tail, dtype)
+        p["tail"] = init_stack(k_tail, cfg, "ssm", n_tail, dtype)
     return p
+
+
+def shared_fwd(blk: dict, adapter: dict, x, emb, cfg: ModelConfig, *,
+               positions, window: int, cache, layer, pctx: ParallelCtx):
+    """One application of a shared block: ``mlp(norm(attn(norm(concat(x,
+    emb)))))`` with the application's adapter on the MLP; no residual.
+    The attention scales its scores by (head_dim / 2) ** -0.5, its
+    head_dim being that of a 2d-wide input. Returns (out, new K/V)."""
+    hd = cfg.resolved_head_dim
+    u = layers.rms_norm(jnp.concatenate([x, emb], -1), blk["ln_in"],
+                        cfg.norm_eps)
+    a, new_cache = attention.attention_block(
+        blk["attn"], u, num_heads=cfg.num_heads,
+        num_kv_heads=cfg.num_kv_heads, head_dim=hd, positions=positions,
+        rope_theta=cfg.rope_theta, causal=True, window=window,
+        kv_cache=cache, layer=layer, impl=pctx.attn_impl,
+        prob_dtype=jnp.bfloat16 if pctx.score_bf16 else jnp.float32,
+        scale=(hd / 2) ** -0.5)
+    y = layers.mlp(blk["mlp"], layers.rms_norm(a, blk["ln_ff"], cfg.norm_eps),
+                   cfg.mlp_type, adapter=adapter)
+    return y, new_cache
+
+
+def _segment_fwd(seg: dict, blk: dict, h, emb, cfg: ModelConfig, *,
+                 positions, window: int, cache, unit, pctx: ParallelCtx):
+    """The plain Mamba layers of one segment, then its hybrid layer.
+    ``cache``: None (training), the unit's own (prefill: filled and
+    returned), or the run's whole stack with ``unit`` the index of the
+    unit (decode: its entries written in place)."""
+    new = {}
+    if "plain" in seg and unit is not None:
+        # decode: layer by layer, each reading and writing its own state
+        # in the stack; a scan over the layers would copy their stacked
+        # weights and states into the loop's layout every step
+        c = cache["plain"]
+        for i in range(jax.tree_util.tree_leaves(seg["plain"])[0].shape[0]):
+            h, c, _ = block_fwd(
+                jax.tree_util.tree_map(lambda a: a[i], seg["plain"]), h, cfg,
+                "ssm", positions=positions, window=window, cache=c,
+                layer=(unit, i), pctx=pctx)
+        new["plain"] = c
+    elif "plain" in seg:
+        h, c, _ = run_stack(seg["plain"], h, cfg, "ssm", positions=positions,
+                            window=window,
+                            caches=None if cache is None else cache["plain"],
+                            pctx=pctx)
+        if cache is not None:
+            new["plain"] = c
+    t, kv = shared_fwd(blk, seg["adapter"], h, emb, cfg, positions=positions,
+                       window=window,
+                       cache=None if cache is None else cache["kv"],
+                       layer=unit, pctx=pctx)
+    t = t @ seg["linear"].astype(t.dtype)
+    h, st, _ = block_fwd(seg["layer"], h, cfg, "ssm", positions=positions,
+                         window=window,
+                         cache=None if cache is None else cache["layer"],
+                         layer=unit, pctx=pctx, inject=t)
+    return h, None if cache is None else dict(new, kv=kv, layer=st)
 
 
 def run_hybrid(params, x, cfg: ModelConfig, *, positions, window: int = 0,
                caches=None, pctx: ParallelCtx):
-    """caches = {"ssm": stacked (n_super, period, ...), "attn": stacked
-    (n_super, ...), "tail": (n_tail, ...)} or None."""
-    n_super, period, n_tail = hybrid_layout(cfg)
+    """Scan each run of ``hybrid_layout`` over its units, then the tail.
+    The embedding ``x`` is carried beside ``h`` into every shared block.
+    caches = {"runs": [[{"plain": (count, n_plain, B, ...) states,
+    "layer": (count, B, ...) states, "kv": (count, B, Smax, H*hd) K/V}]],
+    "tail": (n_tail, B, ...)} or None. A decode step (one token) carries
+    each run's caches through its scan and writes unit j's entries in
+    place: the K/V rows, and the Mamba states, rewritten whole."""
+    runs, n_tail = hybrid_layout(cfg)
     shared = params["shared"]
-
-    def super_body(carry, inp):
-        h = carry
-        p_sb, cache_sb = inp
-        ssm_c = cache_sb["ssm"] if cache_sb is not None else None
-        h, new_ssm, aux = run_stack(
-            p_sb, h, cfg, "ssm", positions=positions, window=window,
-            caches=ssm_c, pctx=dataclasses.replace(pctx),)
-        attn_c = cache_sb["attn"] if cache_sb is not None else None
-        h, new_attn, aux2 = block_fwd(
-            shared, h, cfg, "dense", positions=positions, window=window,
-            causal=True, cache=attn_c, pctx=pctx)
-        new_cache = (None if cache_sb is None
-                     else {"ssm": new_ssm, "attn": new_attn})
-        return h, (new_cache, aux + aux2)
-
+    emb = x
+    kw = dict(positions=positions, window=window, pctx=pctx)
+    new_runs = []
+    for r, (count, plain) in enumerate(runs):
+        p_run = params["runs"][r]
+        if caches is None:
+            def unit_nc(h, p_unit):
+                for s, seg in enumerate(p_unit):
+                    h, _ = _segment_fwd(seg, shared[s], h, emb, cfg,
+                                        cache=None, unit=None, **kw)
+                return h, None
+            x, _ = jax.lax.scan(_maybe_remat(unit_nc, cfg), x, p_run)
+        elif x.shape[1] == 1:
+            def unit_decode(carry, inp):
+                h, c = carry
+                p_unit, j = inp
+                c = list(c)
+                for s, seg in enumerate(p_unit):
+                    h, c[s] = _segment_fwd(seg, shared[s], h, emb, cfg,
+                                           cache=c[s], unit=j, **kw)
+                return (h, c), None
+            (x, c_run), _ = jax.lax.scan(
+                unit_decode, (x, caches["runs"][r]), (p_run, jnp.arange(count)))
+            new_runs.append(c_run)
+        else:
+            def unit_fill(h, inp):
+                p_unit, c_unit = inp
+                out = []
+                for s, seg in enumerate(p_unit):
+                    h, c = _segment_fwd(seg, shared[s], h, emb, cfg,
+                                        cache=c_unit[s], unit=None, **kw)
+                    out.append(c)
+                return h, out
+            x, c_run = jax.lax.scan(_maybe_remat(unit_fill, cfg), x,
+                                    (p_run, caches["runs"][r]))
+            new_runs.append(c_run)
+    aux = jnp.zeros((), jnp.float32)
     if caches is None:
-        def sb_nc(carry, p_sb):
-            h, (_, aux) = super_body(carry, (p_sb, None))
-            return h, aux
-        x, auxs = jax.lax.scan(_maybe_remat(sb_nc, cfg), x, params["blocks"])
-        aux_total = auxs.sum()
-        new_caches = None
         if n_tail:
-            x, _, a = run_stack(params["tail"], x, cfg, "ssm",
-                                positions=positions, window=window, pctx=pctx)
-            aux_total = aux_total + a
-        return x, None, aux_total
-
-    sb_caches = {"ssm": caches["ssm"], "attn": caches["attn"]}
-    if x.shape[1] == 1 and n_super:
-        # decode: carry the stacks and write superblock j's entries in place
-        # (the shared block's K/V row; its Mamba states, rewritten whole)
-        def super_decode(carry, inp):
-            h, c = carry
-            p_sb, j = inp
-            ssm_j = jax.tree_util.tree_map(lambda a: a[j], c["ssm"])
-            h, ssm_j, aux = run_stack(
-                p_sb, h, cfg, "ssm", positions=positions, window=window,
-                caches=ssm_j, pctx=pctx)
-            h, attn, aux2 = block_fwd(
-                shared, h, cfg, "dense", positions=positions, window=window,
-                causal=True, cache=c["attn"], layer=j, pctx=pctx)
-            ssm_c = jax.tree_util.tree_map(lambda a, n: a.at[j].set(n),
-                                           c["ssm"], ssm_j)
-            return (h, {"ssm": ssm_c, "attn": attn}), aux + aux2
-        (x, new_caches), auxs = jax.lax.scan(
-            super_decode, (x, sb_caches),
-            (params["blocks"], jnp.arange(n_super)))
-    else:
-        x, (new_caches, auxs) = jax.lax.scan(
-            _maybe_remat(super_body, cfg), x, (params["blocks"], sb_caches))
-    aux_total = auxs.sum()
+            x, _, _ = run_stack(params["tail"], x, cfg, "ssm", **kw)
+        return x, None, aux
+    new_caches = {"runs": new_runs}
     if n_tail:
-        x, new_tail, a = run_stack(params["tail"], x, cfg, "ssm",
-                                   positions=positions, window=window,
-                                   caches=caches["tail"], pctx=pctx)
-        aux_total = aux_total + a
-        new_caches["tail"] = new_tail
-    return x, new_caches, aux_total
+        x, new_caches["tail"], _ = run_stack(
+            params["tail"], x, cfg, "ssm", caches=caches["tail"], **kw)
+    return x, new_caches, aux

@@ -279,8 +279,8 @@ def attn_kernel_io_bytes(cfg, n_tokens_global: int, mesh, kind: str) -> float:
     total = 0.0
     hd = cfg.resolved_head_dim
     if cfg.num_heads:
-        n_attn = cfg.num_layers if cfg.family != "hybrid" else (
-            cfg.num_layers // max(cfg.hybrid_attn_period, 1))
+        n_attn = cfg.num_layers if cfg.family != "hybrid" else len(
+            cfg.hybrid_layer_ids)
         if cfg.is_encdec:
             n_attn = cfg.num_encoder_layers + 2 * cfg.num_layers
         per_layer = t_l * hd * 2.0 * (2.0 * cfg.num_heads / tp

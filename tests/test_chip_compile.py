@@ -9,6 +9,7 @@ a module-scoped fixture (never at import), so every test worker collects
 the same tests and only the worker given this file loads libtpu.
 """
 import dataclasses
+import json
 import math
 import os
 import re
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
+from repro.configs.base import ModelConfig, SSMConfig
 from repro.kernels.decode_attention import decode_attention_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.fused_rmsnorm import packed_rmsnorm
@@ -74,6 +76,17 @@ def test_flash_attention_compiles_at_stablelm_widths(one_chip, masked):
     assert "tpu_custom_call" in text
 
 
+def test_flash_attention_compiles_at_zamba2_widths(one_chip):
+    """32 heads of 224 (no multiple of the 128 lanes: one block of the
+    full head) over a 2048-token prompt, scores scaled by (224/2)^-0.5."""
+    B, S, H, D = 1, 2048, 32, 224
+    text = _compile(one_chip,
+                    lambda q, k, v: flash_attention_fwd(
+                        q, k, v, scale=(D / 2) ** -0.5),
+                    *[((B, S, H, D), jnp.bfloat16)] * 3)
+    assert "tpu_custom_call" in text
+
+
 def test_packed_gemm_masked_compiles(one_chip):
     J, M, K, N = 8, 512, 768, 768
     text = _compile(one_chip, lambda x, w, a: packed_gemm(x, w, active=a),
@@ -115,6 +128,21 @@ def test_decode_attention_compiles(one_chip, Hq, Hkv, D, S):
                     ((B, Hq, D), jnp.bfloat16),
                     ((L, B, S, Hkv * D), jnp.bfloat16),
                     ((L, B, S, Hkv * D), jnp.bfloat16),
+                    ((B, S), jnp.bool_), ((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_decode_attention_compiles_at_zamba2_widths(one_chip):
+    """The kernel over the zamba2 cell's pool: 2 shared applications, 32
+    lanes, 4096 positions of 32 heads of 224 (7168-wide rows, read in
+    896-wide head blocks), scores scaled by (224/2)^-0.5."""
+    L, B, H, D, S = 2, 32, 32, 224, 4096
+    text = _compile(one_chip,
+                    lambda q, k, v, valid, layer: decode_attention_fwd(
+                        q, k, v, valid, layer, scale=(D / 2) ** -0.5),
+                    ((B, H, D), jnp.bfloat16),
+                    ((L, B, S, H * D), jnp.bfloat16),
+                    ((L, B, S, H * D), jnp.bfloat16),
                     ((B, S), jnp.bool_), ((), jnp.int32))
     assert "tpu_custom_call" in text
 
@@ -171,3 +199,66 @@ def test_stablelm_decode_keeps_the_pool_in_place(one_chip, max_len):
             continue
         others.append(line.strip()[:160])
     assert not others, others
+
+
+def _zamba2_stage():
+    """The benchmark's zamba2-7b stage: 12 layers at published widths,
+    bf16 weights and compute, on the Pallas path."""
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "zamba2-7b.json")) as f:
+        m = json.load(f)["model"]
+    cfg = ModelConfig(**dict(m, ssm=SSMConfig(**m["ssm"])))
+    return build_model(cfg, ParallelCtx(attn_impl="pallas"))
+
+
+def test_zamba2_prefill_holds_flash_kernel(one_chip):
+    """The stage's prefill compiles with the flash kernel at 32 heads of
+    224, once for each of its two shared applications."""
+    model = _zamba2_stage()
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    text = jax.jit(make_prefill(model, 1024)).lower(
+        params, {"tokens": tokens}).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_zamba2_decode_keeps_the_pool_in_place(one_chip):
+    """The stage's decode step at the cell's 32 lanes and 4096 positions:
+    every op whose output is the size of a K/V pool leaf is an in-place
+    row write or a bitcast of the pool, the decode kernel reads it, and no
+    float32 copy or transpose as large as one layer's scan state is made
+    (the Mamba states are read and rewritten where they lie)."""
+    model = _zamba2_stage()
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    lanes, max_len = 32, 4096
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = on_chip(jax.eval_shape(lambda: model.make_cache(lanes, max_len)))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((lanes, 1), jnp.int32),
+                     "pos": jax.ShapeDtypeStruct((lanes,), jnp.int32)})
+    compiled = jax.jit(make_serve_step(model), donate_argnums=(2,)).lower(
+        params, batch, pool).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    kv = pool["runs"][0][0]["kv"]["k"].size           # (1, 32, 4096, 7168)
+    state = pool["runs"][0][0]["layer"]["ssm"].size   # (1, 32, 112, 64, 64)
+    others = []
+    for line in text.splitlines():
+        m = re.search(r"= (\w+)\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(", line)
+        if not (m and m.group(2)):
+            continue
+        size, op = math.prod(int(d) for d in m.group(2).split(",")), m.group(3)
+        if size >= kv and op not in (
+                "parameter", "get-tuple-element", "scatter", "bitcast") and \
+                not (op == "fusion" and '/scatter"' in line):
+            others.append(line.strip()[:160])
+        if m.group(1) == "f32" and size >= state and op in ("copy",
+                                                             "transpose"):
+            others.append(line.strip()[:160])
+    assert not others, others
+    # weights 3.52 GB and pool 8.34 GB in place; temporaries stay small
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9

@@ -95,14 +95,27 @@ def test_attach_lane_donates_the_pool(small):
                 if o[0] in ("copy", "transpose", "broadcast")]
 
 
+def _hybrid_irregular():
+    """The zamba2 block at smoke widths over the published layout's three
+    shapes: hybrid layers at 2, 3, 5 and 7 of 9 give a first unit of
+    unequal segments (2 plain layers, then none), a unit of (1, 1) and a
+    plain tail layer."""
+    return dataclasses.replace(configs.get("zamba2-7b").reduced(),
+                               num_layers=9, hybrid_layer_ids=(2, 3, 5, 7))
+
+
 def test_lane_axes_follow_the_batch():
-    cfg = dataclasses.replace(configs.get("zamba2-7b").reduced(),
-                              num_layers=5)
-    axes = serve.lane_axes(build_model(cfg), 16)
-    # Mamba states of a superblock are (n_super, period, B, ...); the
-    # shared block's K/V and the tail are (n, B, ...)
-    assert axes["ssm"] == {"conv": 2, "ssm": 2}
-    assert axes["attn"] == {"k": 1, "v": 1, "len": 1, "pos": 1}
+    axes = serve.lane_axes(build_model(_hybrid_irregular()), 16)
+    # a run's plain Mamba states are (units, layers, B, ...); the hybrid
+    # layer's state and the shared block's K/V (units, B, ...); the tail
+    # (n, B, ...)
+    first, second = axes["runs"]
+    assert first[0]["plain"] == {"conv": 2, "ssm": 2}
+    assert "plain" not in first[1]
+    assert second[1]["plain"] == {"conv": 2, "ssm": 2}
+    for seg in first + second:
+        assert seg["layer"] == {"conv": 1, "ssm": 1}
+        assert seg["kv"] == {"k": 1, "v": 1, "len": 1, "pos": 1}
     assert axes["tail"] == {"conv": 1, "ssm": 1}
 
 
@@ -155,6 +168,7 @@ def test_decode_kernel_matches_xla_path(B, Hq, Hkv, D, S, window):
     (32, 64, 4096, 8, 1024),    # ... at its published context
     (3, 64, 4096, 3, 2048),     # odd head count: the full 192-wide row
     (2, 16, 24, 2, 24),         # narrower than a tile: the full row
+    (32, 224, 4096, 4, 512),    # zamba2-7b: the narrowest legal, 896 wide
     (8, 128, 32768, 4, 1024)])
 def test_decode_kernel_blocks(Hkv, D, S, hb, bs):
     """Head blocks are a multiple of 128 lanes wide or the full row, and
@@ -172,7 +186,7 @@ def test_decode_kernel_refuses_an_undividable_cache():
 def _family(name):
     cfg = configs.get(name).reduced()
     if cfg.family == "hybrid":
-        cfg = dataclasses.replace(cfg, num_layers=5)   # 2 superblocks + tail
+        cfg = _hybrid_irregular()
     # a routed MoE (not the dense oracle) so that capacity is exercised
     return build_model(cfg, ParallelCtx(moe_oracle=cfg.family != "moe"))
 
