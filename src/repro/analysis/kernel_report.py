@@ -7,7 +7,8 @@
 The JSON report is the machine-readable contract ROADMAP 3(b)'s
 scalar-prefetch grid pruning consumes: per kernel, which index maps
 are affine (rewritable to a prefetched index vector), which are
-affine-with-div (prunable with a gather), whether the kernel already
+affine-with-div (prunable with a gather), which are pruned already
+(clamped by prefetched bounds), whether the kernel already
 carries a lane predicate, and the modeled HBM bytes per grid step. A
 kernel is marked ``prunable`` when it is lane-gated AND every input
 index map is statically rewritable — exactly the precondition for
@@ -63,8 +64,8 @@ def _kernel_entry(mod: SourceModule, m: pm.PallasCallModel,
     lane = any(pm.kernel_is_lane_gated(mod, b) for b in bodies)
     bytes_per_step, unresolved = m.bytes_per_step()
     in_maps = [s.index_map for s in m.in_specs if s.index_map is not None]
-    rewritable = all(im.classification in (pm.AFFINE, pm.AFFINE_DIV)
-                     for im in in_maps)
+    rewritable = all(im.classification in (pm.AFFINE, pm.AFFINE_DIV,
+                                           pm.PRUNED) for im in in_maps)
     return {
         "path": m.relpath,
         "entry": m.entry,

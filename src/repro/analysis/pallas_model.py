@@ -28,8 +28,14 @@ Index maps are classified per output element and the worst class wins:
     grid-constant terms (prunable by scalar-prefetch index rewriting);
   * ``affine_div``  — a grid index under integer division by a
     grid-constant (the GQA ``h // G`` map; prunable with a gather);
+  * ``pruned``      — an affine grid index clamped (``jnp.minimum`` /
+    ``jnp.maximum`` / ``jnp.clip``) by scalar-prefetched bounds read at
+    a grid index or a constant (``min(max(i, lo[b]), hi[b])``): the
+    scalar-prefetch pruning itself, each step outside the bounds
+    repeating a block index and so starting no DMA;
   * ``non_affine``  — anything else (data-dependent or multiplicative
-    in two grid indices; not statically prunable).
+    in two grid indices, or clamped by a value that is not prefetched;
+    not statically prunable).
 """
 from __future__ import annotations
 
@@ -41,9 +47,13 @@ from repro.analysis.core import SourceModule, resolve_call_name
 
 AFFINE = "affine"
 AFFINE_DIV = "affine_div"
+PRUNED = "pruned"
 NON_AFFINE = "non_affine"
 
-_CLASS_RANK = {AFFINE: 0, AFFINE_DIV: 1, NON_AFFINE: 2}
+_CLASS_RANK = {AFFINE: 0, AFFINE_DIV: 1, PRUNED: 2, NON_AFFINE: 3}
+
+#: the calls that clamp an index (by their attribute name)
+_CLAMPS = ("minimum", "maximum", "clip")
 
 PALLAS_CALL = "jax.experimental.pallas.pallas_call"
 BLOCK_SPEC = "jax.experimental.pallas.BlockSpec"
@@ -259,11 +269,52 @@ def _contains_param(node: ast.AST, params: Set[str]) -> bool:
                for n in ast.walk(node))
 
 
+def _is_prefetched_bound(node: ast.AST, params: Set[str],
+                         prefetch: Set[str]) -> bool:
+    """An element of a scalar-prefetch ref at a grid index or a
+    constant (``lo[b]``)."""
+    return (isinstance(node, ast.Subscript)
+            and isinstance(node.value, ast.Name)
+            and node.value.id in prefetch
+            and (isinstance(node.slice, ast.Constant)
+                 or (isinstance(node.slice, ast.Name)
+                     and node.slice.id in params - prefetch)))
+
+
+def _classify_clamp(node: ast.Call, params: Set[str],
+                    prefetch: Set[str]) -> str:
+    """``pruned`` for a clamp whose every argument is a prefetched bound
+    or an index (an affine expression in the grid indices, or a nested
+    clamp of this kind), with at least one of each; else
+    ``non_affine``."""
+    grid = params - prefetch
+    bounds = indices = 0
+    for arg in node.args:
+        if _is_prefetched_bound(arg, params, prefetch):
+            bounds += 1
+        elif (isinstance(arg, ast.Call) and isinstance(arg.func,
+                                                        ast.Attribute)
+              and arg.func.attr in _CLAMPS):
+            if _classify_clamp(arg, params, prefetch) != PRUNED:
+                return NON_AFFINE
+            indices += 1
+        elif (_contains_param(arg, grid)
+              and classify_index_expr(arg, params, prefetch)
+              in (AFFINE, AFFINE_DIV)):
+            indices += 1
+        else:
+            return NON_AFFINE
+    return PRUNED if bounds and indices else NON_AFFINE
+
+
 def classify_index_expr(node: ast.AST, params: Set[str],
                         prefetch: Set[str] = frozenset()) -> str:
     """Classify one index-map output element (see module docstring).
     ``prefetch`` names the scalar-prefetch refs: an element of one at a
     constant index is read once per call, a grid constant."""
+    if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+            and node.func.attr in _CLAMPS and not node.keywords):
+        return _classify_clamp(node, params, prefetch)
     if isinstance(node, ast.Constant):
         return AFFINE if isinstance(node.value, int) else NON_AFFINE
     if isinstance(node, ast.Name):

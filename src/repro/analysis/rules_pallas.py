@@ -14,9 +14,11 @@ Catalog (details in DESIGN.md §14):
           prefetch operands), and the map's output tuple arity == the
           BlockSpec's block-shape rank.
   PAL402  index-map prunability: flag non-affine maps. Classification
-          (affine / affine_div / non_affine) also feeds the pruning-
-          readiness report (kernel_report.py) that ROADMAP 3(b)'s
-          scalar-prefetch grid pruning consumes.
+          (affine / affine_div / pruned / non_affine) also feeds the
+          pruning-readiness report (kernel_report.py) that ROADMAP
+          3(b)'s scalar-prefetch grid pruning consumes; ``pruned`` is
+          that pruning done (a grid index clamped by prefetched
+          bounds).
   PAL403  lane masking must reach the kernel: every kernel registered
           in ``MASKED_KERNELS`` must gate its dot/einsum ops (or, for
           dot-free kernels, its ref writes) behind ``pl.when`` on an

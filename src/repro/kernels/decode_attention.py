@@ -25,7 +25,16 @@ into a float32 layout of its own, every step).
     product are the outputs;
   * the math of ``attention.sdpa_decode`` in float32 (the added terms are
     exact zeros); validity (written, causal, inside the window) comes in
-    as a ``(B, 1, S)`` int32 mask computed from the cache's ``pos``.
+    as a ``(B, 1, S)`` int32 mask computed from the cache's ``pos``;
+  * each lane reads only its live position blocks: the first and last
+    block holding a valid position come in as scalar-prefetched bounds,
+    the K, V and mask index maps clamp the position block into them (a
+    block index that repeats between grid steps starts no new DMA) and
+    the body runs only inside them. A block outside holds no valid
+    position, so it would add exact zeros to the online softmax: the
+    output of every lane with a valid position is the same as reading
+    every block. A lane with none reads block 0 alone (a finite output
+    nobody reads).
 """
 from __future__ import annotations
 
@@ -71,10 +80,31 @@ def seq_block(seq: int, width: int) -> int:
     return max(fits)
 
 
-def _kernel(layer_ref, q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
-            acc_scr, *, groups: int, heads: int, head_dim: int,
-            scale: float):
+def position_block(seq: int, num_kv_heads: int, head_dim: int) -> int:
+    """Cache positions per grid step over a cache of ``seq`` positions of
+    ``num_kv_heads`` heads of ``head_dim``: a lane at position p (and
+    every position before it valid) reads ``p // position_block + 1`` of
+    the ``seq // position_block`` blocks."""
+    return seq_block(seq, head_block(num_kv_heads, head_dim) * head_dim)
+
+
+def _live_blocks(valid: jax.Array, bs: int) -> tuple:
+    """(first, last) (B,) int32: each lane's first and last position
+    block of ``bs`` holding a valid position; (0, 0) for a lane with
+    none."""
+    B, S = valid.shape
+    live = valid.reshape(B, S // bs, bs).any(axis=2)
+    blocks = jnp.arange(S // bs, dtype=jnp.int32)
+    first = jnp.min(jnp.where(live, blocks, S // bs), axis=1)
+    last = jnp.max(jnp.where(live, blocks, 0), axis=1)
+    return jnp.minimum(first, last), last
+
+
+def _kernel(layer_ref, first_ref, last_ref, q_ref, k_ref, v_ref, valid_ref,
+            o_ref, m_scr, l_scr, acc_scr, *, groups: int, heads: int,
+            head_dim: int, scale: float):
     del layer_ref                                    # used by the index maps
+    b = pl.program_id(0)
     si = pl.program_id(2)
 
     @pl.when(si == 0)
@@ -83,18 +113,20 @@ def _kernel(layer_ref, q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (G*hb, hb*D)
-    k = k_ref[0, 0].astype(jnp.float32)              # (bs, hb*D)
-    v = v_ref[0, 0].astype(jnp.float32)              # (bs, hb*D)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    s = jnp.where(valid_ref[0] > 0, s, NEG_INF)      # (G*hb, bs)
-    m_prev = m_scr[...]                              # (G*hb, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jnp.dot(p, v)
-    m_scr[...] = m_new
+    @pl.when((si >= first_ref[b]) & (si <= last_ref[b]))
+    def _block():
+        q = q_ref[0, 0].astype(jnp.float32)          # (G*hb, hb*D)
+        k = k_ref[0, 0].astype(jnp.float32)          # (bs, hb*D)
+        v = v_ref[0, 0].astype(jnp.float32)          # (bs, hb*D)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        s = jnp.where(valid_ref[0] > 0, s, NEG_INF)  # (G*hb, bs)
+        m_prev = m_scr[...]                          # (G*hb, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jnp.dot(p, v)
+        m_scr[...] = m_new
 
     @pl.when(si == pl.num_programs(2) - 1)
     def _finalize():
@@ -114,14 +146,26 @@ def decode_attention_fwd(q, k, v, valid, layer, *, scale=None,
     """q (B, Hq, D); k/v (L, B, S, Hkv*D); valid (B, S) bool; layer ()
     int. Returns (B, Hq, D) float32: attention of each sequence's query
     over layer ``layer`` of its cache, the scores times ``scale``
-    (D ** -0.5 when None)."""
+    (D ** -0.5 when None). Each lane reads only its live position
+    blocks."""
+    D = q.shape[2]
+    first, last = _live_blocks(valid, position_block(k.shape[2],
+                                                     k.shape[3] // D, D))
+    return _decode_attention(q, k, v, valid, layer, first, last, scale=scale,
+                             interpret=interpret)
+
+
+def _decode_attention(q, k, v, valid, layer, first, last, *, scale,
+                      interpret: bool):
+    """``decode_attention_fwd`` reading lane b's position blocks
+    ``first[b]`` to ``last[b]`` (B,) int32."""
     B, Hq, D = q.shape
     L, _, S, W = k.shape
     Hkv = W // D
     G = Hq // Hkv
     hb = head_block(Hkv, D)
     nb = Hkv // hb
-    bs = seq_block(S, hb * D)
+    bs = position_block(S, Hkv, D)
     # block-diagonal queries per head block: rows (g, h), columns (h', d)
     q6 = q.reshape(B, nb, hb, G, D)
     qbd = jnp.einsum("bjhgd,hk->bjghkd", q6, jnp.eye(hb, dtype=q.dtype))
@@ -133,19 +177,26 @@ def decode_attention_fwd(q, k, v, valid, layer, *, scale=None,
         functools.partial(_kernel, groups=G, heads=hb, head_dim=D,
                           scale=D ** -0.5 if scale is None else scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G * hb, hb * D),
-                             lambda b, j, i, lay: (b, j, 0, 0)),
+                             lambda b, j, i, lay, lo, hi: (b, j, 0, 0)),
                 pl.BlockSpec((1, 1, bs, hb * D),
-                             lambda b, j, i, lay: (lay[0], b, i, j)),
+                             lambda b, j, i, lay, lo, hi: (
+                                 lay[0], b,
+                                 jnp.minimum(jnp.maximum(i, lo[b]), hi[b]),
+                                 j)),
                 pl.BlockSpec((1, 1, bs, hb * D),
-                             lambda b, j, i, lay: (lay[0], b, i, j)),
-                pl.BlockSpec((1, 1, bs), lambda b, j, i, lay: (b, 0, i)),
+                             lambda b, j, i, lay, lo, hi: (
+                                 lay[0], b,
+                                 jnp.minimum(jnp.maximum(i, lo[b]), hi[b]),
+                                 j)),
+                pl.BlockSpec((1, 1, bs), lambda b, j, i, lay, lo, hi: (
+                    b, 0, jnp.minimum(jnp.maximum(i, lo[b]), hi[b]))),
             ],
             out_specs=pl.BlockSpec((1, G, hb * D),
-                                   lambda b, j, i, lay: (b, 0, j)),
+                                   lambda b, j, i, lay, lo, hi: (b, 0, j)),
             scratch_shapes=[
                 pltpu.VMEM((G * hb, 1), jnp.float32),
                 pltpu.VMEM((G * hb, 1), jnp.float32),
@@ -156,6 +207,6 @@ def decode_attention_fwd(q, k, v, valid, layer, *, scale=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lay, qbd, k, v, mask)
+    )(lay, first, last, qbd, k, v, mask)
     # (B, G, Hkv, D) -> query heads in the order h*G + g
     return out.reshape(B, G, Hkv, D).transpose(0, 2, 1, 3).reshape(B, Hq, D)

@@ -33,9 +33,12 @@ every step) beside the attention layers' K/V (one row written a step).
 ``run`` is traced (core/monitor.span): ``serve.pool`` (the empty pool's
 allocation, with its ``cache_bytes`` and ``state_bytes``, the recurrent
 state's share of them), then per loop iteration ``serve.step`` holding
-``serve.decode`` (the step's dispatch, with its live ``lanes`` and
+``serve.decode`` (the step's dispatch, with its live ``lanes``,
 ``kv_positions``: the sum over them of the cache positions each one's
-attention reads, 0 for a model with no attention), ``serve.wait`` (the host waiting for the
+attention reads, and ``kv_blocks`` and ``kv_blocks_read``: the
+decode-attention kernel's position blocks over those lanes' caches, and
+the live ones among them, which are all it reads; all 0 for a model with
+no attention), ``serve.wait`` (the host waiting for the
 next tokens), ``serve.readback`` (their copy to the host) and one
 ``serve.join`` per joining request (``serve.prefill``, ``serve.attach``,
 then the read of its first token).
@@ -50,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.monitor import span
+from repro.kernels.decode_attention import position_block
 from repro.models.model import Model
 
 
@@ -209,6 +213,10 @@ class BatchServer:
         cfg, window = self.model.cfg, self.model.window
         attn_len = 0 if cfg.is_attention_free else (
             min(self.max_len, window) if window else self.max_len)
+        # the decode kernel's position blocks over that length: a lane
+        # reads those up to the one that holds its position
+        bs = 1 if cfg.is_attention_free else position_block(
+            attn_len, cfg.num_kv_heads, cfg.resolved_head_dim)
         cur = np.zeros((C, 1), np.int32)             # per-lane token (B, T=1)
         pos = np.full((C,), S_pad, np.int32)
         lane_req: List[Optional[Request]] = [None] * C
@@ -281,9 +289,11 @@ class BatchServer:
                         resize(desired)
                 if n_live:
                     active = np.array([r is not None for r in lane_req])
+                    seen = np.minimum(pos[active] + 1, attn_len)
                     with span("serve.decode", lanes=n_live,
-                              kv_positions=int(np.minimum(
-                                  pos[active] + 1, attn_len).sum())):
+                              kv_positions=int(seen.sum()),
+                              kv_blocks=n_live * (attn_len // bs),
+                              kv_blocks_read=int((-(-seen // bs)).sum())):
                         logits, pool_cache = self._step(
                             self.params,
                             {"tokens": jnp.asarray(cur),

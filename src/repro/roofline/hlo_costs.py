@@ -62,7 +62,7 @@ PALLAS_TILE_BUDGETS: Dict[str, float] = {
     "src/repro/kernels/fused_rmsnorm.py::fused_rmsnorm": 2101248.0,
     "src/repro/kernels/fused_rmsnorm.py::packed_rmsnorm": 2101248.0,
     "src/repro/kernels/ssd_scan.py::ssd_scan": 148992.0,
-    "src/repro/kernels/decode_attention.py::decode_attention_fwd": 4216832.0,
+    "src/repro/kernels/decode_attention.py::_decode_attention": 4216832.0,
 }
 
 #: Allowed relative drift between the modeled bytes/step and the budget.

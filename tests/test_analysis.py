@@ -523,16 +523,34 @@ def test_cli_seeded_index_map_arity_bug_fails(tmp_path, capsys):
 
 @pytest.mark.parametrize("mutate,rc", [
     (None, 0),
-    (("lambda b, j, i, lay: (lay[0], b, i, j)",
-      "lambda b, j, i: (0, b, i, j)"),
+    (("lambda b, j, i, lay, lo, hi: (b, j, 0, 0)",
+      "lambda b, j, i: (b, j, 0, 0)"),
      1)])
 def test_cli_scalar_prefetch_index_maps(tmp_path, capsys, mutate, rc):
-    """A grid spec with scalar prefetch (decode attention's layer index):
-    its index maps take the grid indices and then the prefetched refs,
-    and one that drops the ref trips PAL401."""
+    """A grid spec with scalar prefetch (decode attention's layer index
+    and block bounds): its index maps take the grid indices and then the
+    prefetched refs, and one that drops the refs trips PAL401."""
     root = _gemm_tree(tmp_path, mutate=mutate, source=REAL_DECODE_ATTENTION)
     assert lint_cli.main(["--root", str(root), "--check"]) == rc
     assert ("PAL401" in capsys.readouterr().out) == bool(rc)
+
+
+@pytest.mark.parametrize("bound", [
+    "S // bs - 1",      # a closure value, not prefetched
+    "j",                # a grid index
+    "hi[b + 1]"])       # a prefetched ref at a computed index
+def test_cli_clamp_by_a_value_not_prefetched_trips_pal402(tmp_path, capsys,
+                                                           bound):
+    """Decode attention's K map clamps its position block into the
+    lane's prefetched bounds ``lo[b]``, ``hi[b]`` (classed ``pruned``);
+    a clamp by anything else is no scalar-prefetch pruning and trips
+    PAL402."""
+    clamp = "jnp.minimum(jnp.maximum(i, lo[b]), hi[b])"
+    root = _gemm_tree(tmp_path, source=REAL_DECODE_ATTENTION,
+                      mutate=(clamp, clamp.replace("hi[b]", bound)))
+    assert lint_cli.main(["--root", str(root), "--check"]) == 1
+    out = capsys.readouterr().out
+    assert "PAL402" in out and "in_specs[1]" in out
 
 
 # -------------------------------------------------------------------------
@@ -541,8 +559,9 @@ def test_cli_scalar_prefetch_index_maps(tmp_path, capsys, mutate, rc):
 
 def test_kernel_report_classifies_all_committed_maps():
     """Acceptance criterion: every committed pallas_call index map is
-    classified — the GQA h // G maps as affine_div, everything else
-    affine."""
+    classified — the GQA h // G maps as affine_div, decode attention's
+    position block clamped into prefetched bounds as pruned (its K, V
+    and mask maps), everything else affine."""
     from repro.analysis.kernel_report import build_report
 
     rep = build_report(default_config())
@@ -550,7 +569,7 @@ def test_kernel_report_classifies_all_committed_maps():
     by_entry = {k["entry"]: k for k in rep["kernels"]}
     assert set(by_entry) == {"flash_attention_fwd", "fused_rmsnorm",
                              "packed_rmsnorm", "packed_gemm", "ssd_scan",
-                             "decode_attention_fwd"}
+                             "_decode_attention"}
     for k in rep["kernels"]:
         for spec in k["operands"]:
             if spec["index_map"] is None:
@@ -558,8 +577,13 @@ def test_kernel_report_classifies_all_committed_maps():
                 continue
             for expr, cls in zip(spec["index_map"]["exprs"],
                                  spec["index_map"]["classes"]):
-                expected = "affine_div" if "//" in expr else "affine"
+                expected = ("pruned" if "jnp.minimum" in expr else
+                            "affine_div" if "//" in expr else "affine")
                 assert cls == expected, (k["entry"], expr, cls)
+    decode = [s["index_map"]["classification"]
+              for s in by_entry["_decode_attention"]["operands"]]
+    # q, then K, V and the mask, then the output
+    assert decode == ["affine", "pruned", "pruned", "pruned", "affine"]
     flash = by_entry["flash_attention_fwd"]
     kv_classes = [s["index_map"]["classification"]
                   for s in flash["operands"]

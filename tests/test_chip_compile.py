@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.configs.base import ModelConfig, SSMConfig
-from repro.kernels.decode_attention import decode_attention_fwd
+from repro.kernels.decode_attention import (decode_attention_fwd,
+                                            position_block)
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.fused_rmsnorm import packed_rmsnorm
 from repro.kernels.packed_gemm import packed_gemm
@@ -132,11 +133,29 @@ def test_decode_attention_compiles(one_chip, Hq, Hkv, D, S):
     assert "tpu_custom_call" in text
 
 
+def test_decode_attention_compiles_at_the_stablelm_cell(one_chip):
+    """The kernel over the stablelm cell's pool: 8 lanes of 1024
+    positions of 32 heads of 64, one position block, so each lane reads
+    the whole cache as one block, as before it read only live blocks."""
+    L, B, H, D, S = 24, 8, 32, 64, 1024
+    assert position_block(S, H, D) == S
+    text = _compile(one_chip,
+                    lambda q, k, v, valid, layer: decode_attention_fwd(
+                        q, k, v, valid, layer),
+                    ((B, H, D), jnp.bfloat16),
+                    ((L, B, S, H * D), jnp.bfloat16),
+                    ((L, B, S, H * D), jnp.bfloat16),
+                    ((B, S), jnp.bool_), ((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_decode_attention_compiles_at_zamba2_widths(one_chip):
     """The kernel over the zamba2 cell's pool: 2 shared applications, 32
     lanes, 4096 positions of 32 heads of 224 (7168-wide rows, read in
-    896-wide head blocks), scores scaled by (224/2)^-0.5."""
+    896-wide head blocks), scores scaled by (224/2)^-0.5; eight position
+    blocks of 512, of which each lane reads its live ones."""
     L, B, H, D, S = 2, 32, 32, 224, 4096
+    assert S // position_block(S, H, D) == 8
     text = _compile(one_chip,
                     lambda q, k, v, valid, layer: decode_attention_fwd(
                         q, k, v, valid, layer, scale=(D / 2) ** -0.5),

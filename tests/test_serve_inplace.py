@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.core.monitor import span_log
 from repro.kernels import decode_attention
 from repro.launch import serve
 from repro.launch.serve import BatchServer, Request
@@ -119,22 +120,36 @@ def test_lane_axes_follow_the_batch():
     assert axes["tail"] == {"conv": 1, "ssm": 1}
 
 
-@pytest.mark.parametrize("B,Hq,Hkv,D,S,window", [
-    (3, 4, 2, 16, 24, 0),       # GQA
-    (2, 4, 4, 16, 40, 8),       # windowed
-    (2, 16, 16, 64, 128, 0),    # several head blocks
-    (1, 2, 1, 128, 8192, 0),    # several position blocks
-    (1, 2, 1, 128, 8192, 1000)])  # ... the first of them all masked
-def test_decode_kernel_matches_xla_path(B, Hq, Hkv, D, S, window):
+@pytest.mark.parametrize("B,Hq,Hkv,D,S,window,ends", [
+    pytest.param(3, 4, 2, 16, 24, 0, None, id="3-4-2-16-24-0"),     # GQA
+    pytest.param(2, 4, 4, 16, 40, 8, None, id="2-4-4-16-40-8"),     # windowed
+    # several head blocks
+    pytest.param(2, 16, 16, 64, 128, 0, None, id="2-16-16-64-128-0"),
+    # several position blocks
+    pytest.param(1, 2, 1, 128, 8192, 0, None, id="1-2-1-128-8192-0"),
+    # ... the first of them all masked
+    pytest.param(1, 2, 1, 128, 8192, 1000, None, id="1-2-1-128-8192-1000"),
+    # four blocks of 1024: one valid position, the last and the first
+    # position of a block, the full cache, and a lane with none
+    pytest.param(5, 8, 4, 128, 4096, 0, (0, 1023, 1024, 4095, -1),
+                 id="lanes-in-different-blocks"),
+    # windowed lanes whose leading blocks are all masked
+    pytest.param(3, 8, 4, 128, 4096, 1000, (1500, 3000, 4095),
+                 id="window-masks-leading-blocks")])
+def test_decode_kernel_matches_xla_path(B, Hq, Hkv, D, S, window, ends):
     """The Pallas decode attention (interpret mode) gives the XLA path's
     output and the same in-place cache update, reading layer 1 of a
-    3-layer stack."""
+    3-layer stack. ``ends`` puts each lane's new token at a position of
+    its own (-1: a lane with no valid position, whose output is only
+    finite). Reading each lane's live position blocks gives every live
+    lane exactly what reading every block gives."""
     L, n = 3, (5 if S <= 128 else S - 300)
-    ks = jax.random.split(jax.random.PRNGKey(B * S), 4)
+    ks = jax.random.split(jax.random.PRNGKey(B * S), 5)
     params = attention.init_attention(ks[0], Hq * D, Hq, Hkv, D,
                                       jnp.bfloat16)
     x = jax.random.normal(ks[1], (B, 1, Hq * D)).astype(jnp.bfloat16)
-    lens = n + jnp.arange(B)
+    lens = n + jnp.arange(B) if ends is None else jnp.array(ends)
+    live = np.asarray(lens >= 0)
     slots = jnp.arange(S)[None, :]
     pos = jnp.where(slots < lens[:, None], slots, -1).astype(jnp.int32)
     cache = {"k": jax.random.normal(ks[2], (L, B, S, Hkv * D)).astype(
@@ -150,8 +165,10 @@ def test_decode_kernel_matches_xla_path(B, Hq, Hkv, D, S, window):
             positions=lens[:, None].astype(jnp.int32), rope_theta=1e4,
             window=window, kv_cache=cache, layer=jnp.int32(1), impl=impl)
     np.testing.assert_allclose(
-        np.asarray(out["xla"][0], np.float32),
-        np.asarray(out["pallas_interpret"][0], np.float32), atol=1e-2)
+        np.asarray(out["xla"][0], np.float32)[live],
+        np.asarray(out["pallas_interpret"][0], np.float32)[live], atol=1e-2)
+    assert np.isfinite(np.asarray(out["pallas_interpret"][0],
+                                  np.float32)).all()
     for a, b in zip(jax.tree_util.tree_leaves(out["xla"][1]),
                     jax.tree_util.tree_leaves(out["pallas_interpret"][1])):
         assert jnp.array_equal(a, b)
@@ -160,7 +177,22 @@ def test_decode_kernel_matches_xla_path(B, Hq, Hkv, D, S, window):
     assert jnp.array_equal(new["len"][1], lens + 1)
     assert jnp.array_equal(new["len"][0], cache["len"][0])
     changed = np.asarray(new["pos"] != cache["pos"])
-    assert changed.sum() == B and changed[1].sum() == B
+    assert changed.sum() == live.sum() and changed[1].sum() == live.sum()
+    # the live blocks alone against every block, on the updated cache
+    p, cur = new["pos"][1], lens[:, None]
+    valid = (p >= 0) & (p <= cur)
+    if window:
+        valid = valid & (p > cur - window)
+    q = jax.random.normal(ks[4], (B, Hq, D)).astype(jnp.bfloat16)
+    nblk = S // decode_attention.position_block(S, Hkv, D)
+    read = decode_attention.decode_attention_fwd(
+        q, new["k"], new["v"], valid, jnp.int32(1), interpret=True)
+    every = decode_attention._decode_attention(
+        q, new["k"], new["v"], valid, jnp.int32(1),
+        jnp.zeros((B,), jnp.int32), jnp.full((B,), nblk - 1, jnp.int32),
+        scale=None, interpret=True)
+    assert np.isfinite(np.asarray(read)).all()
+    assert jnp.array_equal(read[live], every[live])
 
 
 @pytest.mark.parametrize("Hkv,D,S,hb,bs", [
@@ -176,6 +208,34 @@ def test_decode_kernel_blocks(Hkv, D, S, hb, bs):
     assert decode_attention.head_block(Hkv, D) == hb
     assert decode_attention.seq_block(S, hb * D) == bs
     assert bs * hb * D <= decode_attention.BLOCK_ELEMS or bs == S
+
+
+@pytest.mark.parametrize("max_len,blocks,P", [(2048, 2, 1020),
+                                              (1024, 1, 1012)])
+def test_decode_spans_count_the_blocks_the_kernel_reads(max_len, blocks, P):
+    """Every ``serve.decode`` span counts the kernel's position blocks
+    over its live lanes and those it reads: at step t each live lane is
+    at position P + t and reads the blocks up to the one holding it."""
+    model = _small_stablelm()
+    params = model.init(jax.random.PRNGKey(2))
+    bs = decode_attention.position_block(max_len, 8, 64)
+    assert max_len // bs == blocks
+    max_news = (3, 6, 9)
+    rng = np.random.default_rng(0)
+    reqs = [Request(id=i, prompt=rng.integers(1, 1024, P).astype(np.int32),
+                    max_new=m) for i, m in enumerate(max_news)]
+    before = {s.index for s in span_log()}
+    BatchServer(model, params, batch_lanes=3, max_len=max_len).run(reqs)
+    decodes = [s.counts for s in span_log()
+               if s.index not in before and s.name == "serve.decode"]
+    assert len(decodes) == max(max_news) - 1
+    for t, c in enumerate(decodes):
+        live = sum(m - 1 > t for m in max_news)
+        assert c["lanes"] == live
+        assert c["kv_blocks"] == live * blocks
+        assert c["kv_blocks_read"] == live * -(-(P + t + 1) // bs)
+    shares = [c["kv_blocks_read"] / c["kv_blocks"] for c in decodes]
+    assert shares[0] == 1 / blocks and shares[-1] == 1.0
 
 
 def test_decode_kernel_refuses_an_undividable_cache():
