@@ -33,7 +33,7 @@ per-slot, and batches are keyed (task, step), so draining a pool and
 reattaching every cursor at a different capacity is bit-identical to an
 uninterrupted run. ``RefillExecutor(repack_policy=...)`` performs the
 swap between two masked steps; ``launch/sweep.py`` (``adaptive_pack``)
-and ``launch/serve.py`` (``adaptive_lanes``) ride the same loop, and
+rides that loop, and
 ``core/simulate.py`` prices ``repack_latency_s`` so ``compare_modes``
 can weigh the policy against a static oracle. DESIGN.md §9.
 """

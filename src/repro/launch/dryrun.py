@@ -42,9 +42,7 @@ LONG_WINDOW = {"zamba2-7b": 4096}
 def build_model(arch: str, shape: ShapeSpec, mesh,
                 overrides: Optional[dict] = None,
                 opt: Optional[dict] = None) -> Model:
-    """opt: perf-iteration flags (§Perf) —
-    pad_heads: TP head padding; score_bf16: bf16 softmax-prob traffic;
-    ep_bf16: bf16 EP combine psum."""
+    """opt: perf-iteration flags (§Perf) — pad_heads: TP head padding."""
     opt = opt or {}
     cfg = configs.get(arch)
     if overrides:
@@ -54,9 +52,7 @@ def build_model(arch: str, shape: ShapeSpec, mesh,
     window = None
     if shape.name == "long_500k":
         window = LONG_WINDOW.get(arch)
-    pctx = ParallelCtx(mesh=mesh, ep=(cfg.family == "moe"),
-                       score_bf16=bool(opt.get("score_bf16")),
-                       ep_bf16=bool(opt.get("ep_bf16")))
+    pctx = ParallelCtx(mesh=mesh, ep=(cfg.family == "moe"))
     return Model(cfg, pctx=pctx, window=window)
 
 

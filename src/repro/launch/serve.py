@@ -7,14 +7,12 @@ concurrent-jobs-per-GPU packing, on the persistent-lane-pool model of
 core/lanepool.py):
 
   * the decode state is a fixed-capacity pool: the model's own decode
-    cache at batch = lanes, layer-major (``(L, lanes, Smax, H*D)`` for
-    K/V), the layout the layer scan carries. Decode is the model's batched
-    ``decode_step``, compiled ONCE per pool width; each step writes only
-    the new token's K/V row per layer and lane into the donated pool, so
-    no step copies, transposes or re-stacks it;
+    cache at batch = lanes (``Model.make_cache``). Decode is the model's
+    batched ``decode_step``, compiled ONCE per pool width; each step
+    updates the donated pool in place, so no step copies it;
   * a request joins MID-DECODE the moment a lane frees: its prompt is
     prefilled at batch 1 and its cache written into the free lane by one
-    jitted, donated ``attach_lane`` (no recompilation, other lanes
+    jitted, donated ``Model.attach_lane`` (no recompilation, other lanes
     undisturbed);
   * a finished lane stops burning decode budget — its request is retired
     immediately (``Request.done``) and the next queued request takes the
@@ -24,36 +22,31 @@ core/lanepool.py):
 Lanes are independent: every op of a decode step is per sequence (a
 routed MoE step gives every token room at every expert), so a request's
 tokens are identical whatever co-residents it decodes next to (prompts are
-left-padded to one fixed length per ``run``).
-
-A pool may hold two kinds of state side by side (zamba2): each Mamba
-layer's recurrent state (``conv`` and ``ssm`` leaves, rewritten whole by
-every step) beside the attention layers' K/V (one row written a step).
+left-padded to one fixed length per ``run``). The pool's format (its
+leaves, their lane axes, the attention's cache length and the decode
+kernel's blocks) is the model's alone; this loop only moves requests
+through lanes.
 
 ``run`` is traced (core/monitor.span): ``serve.pool`` (the empty pool's
 allocation, with its ``cache_bytes`` and ``state_bytes``, the recurrent
-state's share of them), then per loop iteration ``serve.step`` holding
-``serve.decode`` (the step's dispatch, with its live ``lanes``,
-``kv_positions``: the sum over them of the cache positions each one's
-attention reads, and ``kv_blocks`` and ``kv_blocks_read``: the
-decode-attention kernel's position blocks over those lanes' caches, and
-the live ones among them, which are all it reads; all 0 for a model with
-no attention), ``serve.wait`` (the host waiting for the
-next tokens), ``serve.readback`` (their copy to the host) and one
-``serve.join`` per joining request (``serve.prefill``, ``serve.attach``,
-then the read of its first token).
+state's share of them: ``Model.pool_bytes``), then per loop iteration
+``serve.step`` holding ``serve.decode`` (the step's dispatch, with its
+live ``lanes`` and what their attention reads, ``Model.decode_reads``:
+``kv_positions``, ``kv_blocks`` and ``kv_blocks_read``), ``serve.wait``
+(the host waiting for the next tokens), ``serve.readback`` (their copy to
+the host) and one ``serve.join`` per joining request (``serve.prefill``,
+``serve.attach``, then the read of its first token).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.monitor import span
-from repro.kernels.decode_attention import position_block
 from repro.models.model import Model
 
 
@@ -70,41 +63,6 @@ def make_serve_step(model: Model) -> Callable:
     return serve_step
 
 
-def lane_axes(model: Model, max_len: int) -> Any:
-    """The batch axis of each leaf of ``model``'s decode cache (the axis
-    whose size follows the batch), as a pytree of ints."""
-    one, two = (jax.eval_shape(lambda b=b: model.make_cache(b, max_len))
-                for b in (1, 2))
-    return jax.tree_util.tree_map(
-        lambda a, b: next(i for i, (m, n) in enumerate(zip(a.shape, b.shape))
-                          if m != n), one, two)
-
-
-#: the leaves of a decode cache that hold recurrent (SSM) state
-STATE_LEAVES = ("conv", "ssm")
-
-
-def cache_bytes(shapes: Any) -> Tuple[int, int]:
-    """(all bytes, recurrent-state bytes) of a decode cache's shapes."""
-    total = state = 0
-    for path, x in jax.tree_util.tree_flatten_with_path(shapes)[0]:
-        n = x.size * x.dtype.itemsize
-        total += n
-        if getattr(path[-1], "key", None) in STATE_LEAVES:
-            state += n
-    return total, state
-
-
-def make_attach_lane(axes: Any) -> Callable:
-    """(pool, lane_cache, lane) -> pool with the batch-1 ``lane_cache``
-    written into lane ``lane`` (traced) of every leaf, on its batch axis."""
-    def attach_lane(pool, lane_cache, lane):
-        return jax.tree_util.tree_map(
-            lambda p, x, ax: jax.lax.dynamic_update_slice_in_dim(
-                p, x.astype(p.dtype), lane, axis=ax), pool, lane_cache, axes)
-    return attach_lane
-
-
 @dataclasses.dataclass
 class Request:
     id: int
@@ -117,57 +75,27 @@ class Request:
 @dataclasses.dataclass
 class ServeStats:
     """Decode accounting for the last ``BatchServer.run``."""
-    global_steps: int = 0         # vmapped decode invocations
+    global_steps: int = 0         # batched decode invocations
     lane_steps: int = 0           # tokens produced (invariant: Σ max_new)
-    lane_slots: int = 0           # lane-slots stepped (Σ pool width/step —
-                                  # what adaptive resizing shrinks)
     prefills: int = 0
     n_requests: int = 0
-    resizes: int = 0              # adaptive lane-pool rebuilds
-    lane_trace: List[Tuple[int, int]] = dataclasses.field(
-        default_factory=list)     # (global_step, lane count) per resize
-
-    @property
-    def occupancy(self) -> float:
-        if not self.global_steps:
-            return 0.0
-        return self.lane_steps / self.global_steps
-
-    @property
-    def step_efficiency(self) -> float:
-        """Fraction of stepped lane-slots that produced a kept token."""
-        if not self.lane_slots:
-            return 0.0
-        return self.lane_steps / self.lane_slots
 
 
 class BatchServer:
-    """Greedy-decode server over a persistent lane pool.
+    """Greedy-decode server over a persistent lane pool of
+    ``batch_lanes`` lanes, each holding up to ``max_len`` positions."""
 
-    With ``adaptive_lanes`` the pool RESIZES to queue depth between decode
-    steps (the serving face of online elastic repacking, core/repack.py):
-    as the request tail drains, live lanes are compacted into a smaller
-    pool so the batched step stops paying for dead lanes. Lane counts are
-    rounded to powers of two, so at most log2(batch_lanes) decode variants
-    ever compile; per-request tokens are unchanged (lanes are
-    independent).
-    """
-
-    def __init__(self, model: Model, params, batch_lanes: int, max_len: int,
-                 adaptive_lanes: bool = False):
+    def __init__(self, model: Model, params, batch_lanes: int, max_len: int):
         self.model = model
         self.params = params
         self.lanes = batch_lanes
         self.max_len = max_len
-        self.adaptive_lanes = adaptive_lanes
         self.stats = ServeStats()
         self._prefill = jax.jit(make_prefill(model, max_len))
         # the model's batched decode over the whole pool (one sequence per
         # lane), updating the donated pool in place
         self._step = jax.jit(make_serve_step(model), donate_argnums=(2,))
-        self._axes = lane_axes(model, max_len)
-        self._attach = jax.jit(make_attach_lane(self._axes),
-                               donate_argnums=(0,))
+        self._attach = jax.jit(model.attach_lane, donate_argnums=(0,))
         self._empty = jax.jit(model.make_cache, static_argnums=(0, 1))
 
     def run(self, requests: List[Request]) -> Dict[int, List[int]]:
@@ -202,21 +130,10 @@ class BatchServer:
             first = jnp.argmax(logits, -1).astype(jnp.int32)   # (1,)
             return first, cache
 
-        # the pool's shapes are fixed until an adaptive resize
-        shapes = jax.eval_shape(lambda: self.model.make_cache(C, self.max_len))
-        nbytes, state_bytes = cache_bytes(shapes)
+        nbytes, state_bytes = self.model.pool_bytes(C, self.max_len)
         with span("serve.pool", lanes=C, cache_bytes=nbytes,
                   state_bytes=state_bytes):
             pool_cache = self._empty(C, self.max_len)
-        # positions one lane's attention reads at position p: p + 1, up
-        # to the cache's (or the window's) length
-        cfg, window = self.model.cfg, self.model.window
-        attn_len = 0 if cfg.is_attention_free else (
-            min(self.max_len, window) if window else self.max_len)
-        # the decode kernel's position blocks over that length: a lane
-        # reads those up to the one that holds its position
-        bs = 1 if cfg.is_attention_free else position_block(
-            attn_len, cfg.num_kv_heads, cfg.resolved_head_dim)
         cur = np.zeros((C, 1), np.int32)             # per-lane token (B, T=1)
         pos = np.full((C,), S_pad, np.int32)
         lane_req: List[Optional[Request]] = [None] * C
@@ -234,29 +151,6 @@ class BatchServer:
                 cur[lane, 0] = int(first[0])
                 pos[lane] = S_pad
                 lane_req[lane] = r
-
-        def resize(new_c: int):
-            """Compact live lanes into a pool of ``new_c`` lanes: a gather
-            on each leaf's lane axis (per-lane state is untouched)."""
-            nonlocal pool_cache, cur, pos, lane_req, C
-            live = [l for l, r in enumerate(lane_req) if r is not None]
-            # lanes past the live ones hold copies of another lane's cache
-            # until a request attaches there; their tokens are never read
-            take = np.array((live + (live[:1] or [0]) * new_c)[:new_c],
-                            np.int32)
-            pool_cache = jax.tree_util.tree_map(
-                lambda p, ax: jnp.take(p, take, axis=ax), pool_cache,
-                self._axes)
-            new_cur = np.zeros((new_c, 1), np.int32)
-            new_pos = np.full((new_c,), S_pad, np.int32)
-            new_req: List[Optional[Request]] = [None] * new_c
-            for i, l in enumerate(live):
-                new_cur[i] = cur[l]
-                new_pos[i] = pos[l]
-                new_req[i] = lane_req[l]
-            cur, pos, lane_req, C = new_cur, new_pos, new_req, new_c
-            self.stats.resizes += 1
-            self.stats.lane_trace.append((self.stats.global_steps, new_c))
 
         for lane in range(C):
             attach(lane, queue.pop(0))
@@ -281,19 +175,11 @@ class BatchServer:
                 n_live = sum(1 for r in lane_req if r is not None)
                 if n_live == 0 and not queue:
                     break
-                if self.adaptive_lanes:
-                    demand = n_live + len(queue)
-                    desired = 1 << (max(1, demand) - 1).bit_length()
-                    desired = min(self.lanes, max(desired, n_live, 1))
-                    if desired < C:
-                        resize(desired)
                 if n_live:
                     active = np.array([r is not None for r in lane_req])
-                    seen = np.minimum(pos[active] + 1, attn_len)
-                    with span("serve.decode", lanes=n_live,
-                              kv_positions=int(seen.sum()),
-                              kv_blocks=n_live * (attn_len // bs),
-                              kv_blocks_read=int((-(-seen // bs)).sum())):
+                    reads = self.model.decode_reads(pos[active],
+                                                    self.max_len)
+                    with span("serve.decode", lanes=n_live, **reads):
                         logits, pool_cache = self._step(
                             self.params,
                             {"tokens": jnp.asarray(cur),
@@ -305,7 +191,6 @@ class BatchServer:
                     with span("serve.readback"):
                         nxt = np.asarray(nxt, np.int32)
                     self.stats.global_steps += 1
-                    self.stats.lane_slots += C
                     cur[active, 0] = nxt[active]
                     pos[active] += 1         # inactive lanes stay frozen
                 # refill phase — strictly AFTER the step: a joiner's first

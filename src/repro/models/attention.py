@@ -64,7 +64,6 @@ def sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  q_offset: int = 0,
                  chunk_k: int = 1024,
                  kv_valid_len: Optional[jax.Array] = None,
-                 prob_dtype=jnp.float32,
                  scale: Optional[float] = None) -> jax.Array:
     """Online-softmax attention, scanning KV chunks.
 
@@ -83,12 +82,11 @@ def sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     with jax.named_scope("sdpa"):
         return _sdpa_chunked_tagged(q, k, v, causal=causal, window=window,
                                     q_offset=q_offset, chunk_k=chunk_k,
-                                    kv_valid_len=kv_valid_len,
-                                    prob_dtype=prob_dtype, scale=scale)
+                                    kv_valid_len=kv_valid_len, scale=scale)
 
 
 def _sdpa_chunked_tagged(q, k, v, *, causal, window, q_offset, chunk_k,
-                         kv_valid_len, prob_dtype, scale):
+                         kv_valid_len, scale):
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     G = Hq // Hkv
@@ -122,12 +120,8 @@ def _sdpa_chunked_tagged(q, k, v, *, causal, window, q_offset, chunk_k,
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + p.sum(axis=-1)
-        # prob_dtype=bf16 halves the dominant HBM term (p read/write) and
-        # runs the PV matmul at MXU-native precision; fp32 max/denominator
-        # keep the softmax numerics (§Perf H-score-bf16)
         acc = acc * corr[..., None] + jnp.einsum(
-            "bhgqk,bkhd->bhgqd", p.astype(prob_dtype),
-            v_blk.astype(prob_dtype)).astype(jnp.float32)
+            "bhgqk,bkhd->bhgqd", p, v_blk.astype(jnp.float32))
         return (m_new, l_new, acc), None
 
     m0 = jnp.full((B, Hkv, G, Sq), NEG_INF, jnp.float32)
@@ -242,7 +236,6 @@ def attention_block(params: dict, x: jax.Array, *,
                     window: int = 0,
                     kv_cache: Optional[dict] = None,
                     impl: Optional[str] = None,
-                    prob_dtype=jnp.float32,
                     kv_ctx: Optional[jax.Array] = None,
                     layer: Optional[jax.Array] = None,
                     scale: Optional[float] = None) -> tuple:
@@ -288,7 +281,7 @@ def attention_block(params: dict, x: jax.Array, *,
     else:  # train / prefill
         if impl == "xla":
             out = sdpa_chunked(q, k, v, causal=causal, window=window,
-                               prob_dtype=prob_dtype, scale=scale)
+                               scale=scale)
         else:
             out = kops.flash_attention(q, k, v, causal=causal,
                                        window=window, impl=impl, scale=scale)

@@ -2,7 +2,8 @@
 
 ``Model`` wraps init / loss / train-shape forward / prefill / decode_step /
 input_specs behind one interface so the launcher, dry-run, triples packing
-and tests treat all ten architectures uniformly.
+and tests treat all ten architectures uniformly. It also owns the decode
+cache's format, which a server pools at batch = lanes.
 
 Batch layouts (all int32 unless noted):
   train   LM      {"tokens": (B,S), "labels": (B,S)}
@@ -17,16 +18,19 @@ Batch layouts (all int32 unless noted):
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeSpec
+from repro.kernels.decode_attention import position_block
 from repro.models import attention, layers, ssm, transformer
 from repro.models.transformer import ParallelCtx
+
+#: the leaves of a decode cache that hold recurrent (SSM) state
+STATE_LEAVES = ("conv", "ssm")
 
 
 def _dt(name: str):
@@ -126,14 +130,13 @@ class Model:
         w = (params["embed"].T if self.cfg.tie_embeddings
              else params["unembed"]).astype(self.cdt)
         mesh = self.pctx.mesh
-        if mesh is not None and self.pctx.constrain:
+        if mesh is not None:
             # deterministic TP head: GSPMD's dot partitioner materialized
             # full-vocab (B,S,V) fp32 tensors (26 GB/dev on stablelm train)
             # for the jvp/transpose of this dot no matter the constraints;
             # a shard_map leaves it no choice. bwd: dW stays local,
             # dh gets the automatic psum over "model".
             from jax.sharding import PartitionSpec as P
-            import numpy as np
             dp = self.pctx.batch_axes()
             dp_size = int(np.prod([mesh.shape[a] for a in dp]))
             batch_spec = dp if h.shape[0] % dp_size == 0 else None
@@ -168,11 +171,26 @@ class Model:
         return total, {"loss": total, "ce": ce, "aux": aux}
 
     # ------------------------------------------------------------- serving
+    # The decode pool's format is the model's: ``make_cache`` builds it,
+    # and the methods below answer what a server needs to know of it (a
+    # leaf's lane axis, writing a lane, its bytes, the attention's reads).
+
+    def kv_len(self, max_len: int) -> int:
+        """Positions in each attention layer's K/V cache: ``max_len``, or
+        the window where that is shorter; 0 for a model with no
+        attention."""
+        if self.cfg.is_attention_free:
+            return 0
+        return min(max_len, self.window) if self.window else max_len
+
     def make_cache(self, batch_size: int, max_len: int) -> Any:
-        """Decode cache pytree (stacked per layer)."""
+        """Decode cache pytree, stacked per layer (layer-major: K/V
+        ``(L, B, kv_len, Hkv*D)``, the layout the layer scan carries). A
+        hybrid's holds each Mamba layer's recurrent state (rewritten whole
+        by every step) beside the shared blocks' K/V (a row a step)."""
         cfg = self.cfg
         hd = cfg.resolved_head_dim
-        attn_len = min(max_len, self.window) if self.window else max_len
+        attn_len = self.kv_len(max_len)
 
         def kv_stack(n):
             one = lambda: attention.init_kv_cache(
@@ -212,6 +230,55 @@ class Model:
                 "cross_v": jnp.zeros((L, B, max_len, cfg.num_kv_heads, hd), self.cdt),
             }
         raise ValueError(fam)
+
+    def lane_axes(self) -> Any:
+        """The batch axis of each leaf of the decode cache (the axis whose
+        size follows the batch), as a pytree of ints."""
+        one, two = (jax.eval_shape(lambda b=b: self.make_cache(b, 1))
+                    for b in (1, 2))
+        return jax.tree_util.tree_map(
+            lambda a, b: next(i for i, (m, n) in enumerate(zip(a.shape, b.shape))
+                              if m != n), one, two)
+
+    def attach_lane(self, pool, lane_cache, lane):
+        """``pool`` with the batch-1 ``lane_cache`` written into lane
+        ``lane`` (traced) of every leaf, on its batch axis."""
+        return jax.tree_util.tree_map(
+            lambda p, x, ax: jax.lax.dynamic_update_slice_in_dim(
+                p, x.astype(p.dtype), lane, axis=ax),
+            pool, lane_cache, self.lane_axes())
+
+    def pool_bytes(self, batch_size: int, max_len: int) -> Tuple[int, int]:
+        """(all bytes, recurrent-state bytes) of the decode cache at
+        ``batch_size``: the state is the Mamba layers' leaves, rewritten
+        whole by every step, beside K/V written a row a step."""
+        shapes = jax.eval_shape(lambda: self.make_cache(batch_size, max_len))
+        total = state = 0
+        for path, x in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+            n = x.size * x.dtype.itemsize
+            total += n
+            if getattr(path[-1], "key", None) in STATE_LEAVES:
+                state += n
+        return total, state
+
+    def decode_reads(self, positions: np.ndarray,
+                     max_len: int) -> Dict[str, int]:
+        """What one decode step's attention reads for lanes at
+        ``positions`` (host ints) in caches made for ``max_len``:
+        ``kv_positions``, the sum of the positions each lane attends;
+        ``kv_blocks``, the decode kernel's position blocks over those
+        lanes' caches; ``kv_blocks_read``, the blocks up to the one
+        holding each lane's position, which are all the kernel reads.
+        All 0 for a model with no attention."""
+        n = self.kv_len(max_len)
+        if not n:
+            return {"kv_positions": 0, "kv_blocks": 0, "kv_blocks_read": 0}
+        bs = position_block(n, self.cfg.num_kv_heads,
+                            self.cfg.resolved_head_dim)
+        seen = np.minimum(positions + 1, n)
+        return {"kv_positions": int(seen.sum()),
+                "kv_blocks": len(positions) * (n // bs),
+                "kv_blocks_read": int((-(-seen // bs)).sum())}
 
     def prefill(self, params, batch, max_len: int):
         """Full-sequence forward filling a fresh cache. Returns

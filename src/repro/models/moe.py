@@ -143,13 +143,10 @@ def capacity_for(T: int, m: MoEConfig, num_shards: int = 1) -> int:
 
 def moe_routed(params: dict, x: jax.Array, m: MoEConfig, *,
                capacity: Optional[int] = None,
-               ep_axis: Optional[str] = None,
-               combine_dtype=None) -> Tuple[jax.Array, jax.Array]:
+               ep_axis: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """Routed-experts output for x (T,d). Inside a shard_map, set ep_axis to
     the mesh axis name sharding the expert dim of the weights; the psum over
-    that axis completes the combine. ``combine_dtype=bf16`` halves the EP
-    collective payload (§Perf H-ep-bf16); partial sums are at most top_k
-    expert outputs so the precision loss is benign."""
+    that axis completes the combine."""
     E = m.num_experts
     if ep_axis is None:
         cap = capacity if capacity is not None else capacity_for(x.shape[0], m)
@@ -163,11 +160,7 @@ def moe_routed(params: dict, x: jax.Array, m: MoEConfig, *,
     w, idx, aux = route(params["router"], x, m.top_k)
     y = _dispatch_compute_combine(x, w, idx, params, m,
                                   rank * e_local, e_local, cap)
-    if combine_dtype is not None:
-        y = jax.lax.psum(y.astype(combine_dtype), ep_axis).astype(x.dtype)
-    else:
-        y = jax.lax.psum(y, ep_axis)
-    return y, aux
+    return jax.lax.psum(y, ep_axis), aux
 
 
 # ---------------------------------------------------------------------------

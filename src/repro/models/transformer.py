@@ -27,15 +27,11 @@ class ParallelCtx:
     ep            — expert parallelism via shard_map over the "model" axis
     moe_oracle    — tiny dense-oracle MoE path (smoke tests only)
     attn_impl     — attention impl override ("xla"|"pallas"|"pallas_interpret")
-    constrain     — insert with_sharding_constraint at layer boundaries
     """
     mesh: Any = None
     ep: bool = False
     moe_oracle: bool = False
     attn_impl: Optional[str] = None
-    constrain: bool = True
-    score_bf16: bool = False    # §Perf: bf16 softmax-prob traffic
-    ep_bf16: bool = False       # §Perf: bf16 EP combine psum payload
 
     def batch_axes(self):
         if self.mesh is None:
@@ -45,7 +41,7 @@ class ParallelCtx:
 
 def _constrain_act(x, pctx: ParallelCtx):
     """Activations (B, S, d): batch sharded over data axes, rest replicated."""
-    if pctx.mesh is None or not pctx.constrain:
+    if pctx.mesh is None:
         return x
     from jax.sharding import NamedSharding, PartitionSpec as P
     spec = P(pctx.batch_axes(), *([None] * (x.ndim - 1)))
@@ -106,9 +102,7 @@ def _ep_moe_call(p_moe, xt, cfg, pctx: ParallelCtx, dropless: bool = False):
     def body(router, wg, wu, wd, xt_l):
         prm = {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd}
         y, aux = moe.moe_routed(prm, xt_l, m, ep_axis="model",
-                                capacity=xt_l.shape[0] if dropless else None,
-                                combine_dtype=(jnp.bfloat16 if pctx.ep_bf16
-                                               else None))
+                                capacity=xt_l.shape[0] if dropless else None)
         aux = jax.lax.pmean(aux, data_axes)
         return y, aux
 
@@ -129,8 +123,7 @@ def attn_block_fwd(p: dict, x, cfg: ModelConfig, *, positions,
         num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads, head_dim=hd,
         positions=positions, rope_theta=cfg.rope_theta,
         mrope_positions=mrope_positions, causal=causal, window=window,
-        kv_cache=cache, layer=layer, impl=pctx.attn_impl,
-        prob_dtype=jnp.bfloat16 if pctx.score_bf16 else jnp.float32)
+        kv_cache=cache, layer=layer, impl=pctx.attn_impl)
     return out, new_cache
 
 
@@ -361,7 +354,6 @@ def shared_fwd(blk: dict, adapter: dict, x, emb, cfg: ModelConfig, *,
         num_kv_heads=cfg.num_kv_heads, head_dim=hd, positions=positions,
         rope_theta=cfg.rope_theta, causal=True, window=window,
         kv_cache=cache, layer=layer, impl=pctx.attn_impl,
-        prob_dtype=jnp.bfloat16 if pctx.score_bf16 else jnp.float32,
         scale=(hd / 2) ** -0.5)
     y = layers.mlp(blk["mlp"], layers.rms_norm(a, blk["ln_ff"], cfg.norm_eps),
                    cfg.mlp_type, adapter=adapter)

@@ -8,12 +8,11 @@ from repro.launch.serve import BatchServer, Request
 from repro.models import ParallelCtx, build_model
 
 
-def _srv(lanes, max_len=32, adaptive_lanes=False):
+def _srv(lanes, max_len=32):
     cfg = configs.get("stablelm-1.6b").reduced()
     model = build_model(cfg, ParallelCtx(moe_oracle=True))
     params = model.init(jax.random.PRNGKey(0))
-    return BatchServer(model, params, batch_lanes=lanes, max_len=max_len,
-                       adaptive_lanes=adaptive_lanes)
+    return BatchServer(model, params, batch_lanes=lanes, max_len=max_len)
 
 
 def test_decode_steps_equal_sum_max_new_not_batch_times_max():
@@ -100,28 +99,6 @@ def test_enqueue_rejects_requests_past_kv_cache_length():
     assert len(_srv(lanes=2, max_len=8).run([good])[0]) == 4
     exact = Request(id=4, prompt=np.arange(1, 5, dtype=np.int32), max_new=5)
     assert len(_srv(lanes=1, max_len=8).run([exact])[4]) == 5
-
-
-def test_adaptive_lanes_shrink_to_queue_depth_same_tokens():
-    """adaptive_lanes: the pool shrinks to demand as the tail drains —
-    fewer dead lanes in the vmapped step — and every request's tokens are
-    bit-identical to the fixed-pool run (vmap lane independence)."""
-    prompt = np.arange(1, 5, dtype=np.int32)
-    max_news = [2, 3, 12, 2]
-    mk = lambda: [Request(id=i, prompt=prompt, max_new=m)
-                  for i, m in enumerate(max_news)]
-    fixed = _srv(lanes=4)
-    base = fixed.run(mk())
-    srv = _srv(lanes=4, adaptive_lanes=True)
-    out = srv.run(mk())
-    assert out == base
-    assert srv.stats.lane_steps == sum(max_news)
-    assert srv.stats.resizes >= 1               # tail drained: pool shrank
-    assert srv.stats.lane_trace[-1][1] == 1     # lone straggler, 1 lane
-    # same tokens in the same number of steps, but fewer lane-slots paid
-    assert srv.stats.global_steps == fixed.stats.global_steps
-    assert srv.stats.lane_slots < fixed.stats.lane_slots
-    assert srv.stats.step_efficiency > fixed.stats.step_efficiency
 
 
 def test_zero_max_new_request_is_done_immediately():

@@ -9,6 +9,7 @@ pool aliased from input to output), check the decode-attention kernel
 against the XLA path, and check that every family serves a request the
 same tokens whatever it shares the pool with.
 """
+import ast
 import dataclasses
 import re
 
@@ -85,8 +86,7 @@ def test_decode_step_writes_the_pool_in_place(small):
 def test_attach_lane_donates_the_pool(small):
     model, _, pool = small
     lane_cache = jax.eval_shape(lambda: model.make_cache(1, MAX_LEN))
-    attach = jax.jit(serve.make_attach_lane(serve.lane_axes(model, MAX_LEN)),
-                     donate_argnums=(0,))
+    attach = jax.jit(model.attach_lane, donate_argnums=(0,))
     hlo = attach.lower(pool, lane_cache,
                        jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
     assert _aliases(hlo).count("may-alias") == 4
@@ -106,7 +106,7 @@ def _hybrid_irregular():
 
 
 def test_lane_axes_follow_the_batch():
-    axes = serve.lane_axes(build_model(_hybrid_irregular()), 16)
+    axes = build_model(_hybrid_irregular()).lane_axes()
     # a run's plain Mamba states are (units, layers, B, ...); the hybrid
     # layer's state and the shared block's K/V (units, B, ...); the tail
     # (n, B, ...)
@@ -214,11 +214,15 @@ def test_decode_kernel_blocks(Hkv, D, S, hb, bs):
                                               (1024, 1, 1012)])
 def test_decode_spans_count_the_blocks_the_kernel_reads(max_len, blocks, P):
     """Every ``serve.decode`` span counts the kernel's position blocks
-    over its live lanes and those it reads: at step t each live lane is
-    at position P + t and reads the blocks up to the one holding it."""
+    over its live lanes and those it reads (``Model.decode_reads``): at
+    step t each live lane is at position P + t and reads the blocks up to
+    the one holding it."""
     model = _small_stablelm()
     params = model.init(jax.random.PRNGKey(2))
     bs = decode_attention.position_block(max_len, 8, 64)
+    assert model.decode_reads(np.array([P, bs - 1]), max_len) == {
+        "kv_positions": min(P + 1, max_len) + bs,
+        "kv_blocks": 2 * blocks, "kv_blocks_read": -(-(P + 1) // bs) + 1}
     assert max_len // bs == blocks
     max_news = (3, 6, 9)
     rng = np.random.default_rng(0)
@@ -236,6 +240,25 @@ def test_decode_spans_count_the_blocks_the_kernel_reads(max_len, blocks, P):
         assert c["kv_blocks_read"] == live * -(-(P + t + 1) // bs)
     shares = [c["kv_blocks_read"] / c["kv_blocks"] for c in decodes]
     assert shares[0] == 1 / blocks and shares[-1] == 1.0
+
+
+def test_serving_loop_leaves_the_pool_format_to_the_model():
+    """``launch/serve.py`` moves requests through lanes and nothing more:
+    it imports no kernel, names no cache leaf and reads neither the
+    attention window nor whether the model has attention; the model
+    answers all of that."""
+    with open(serve.__file__) as f:
+        tree = ast.parse(f.read())
+    imported = [a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+                for a in n.names]
+    imported += [n.module or "" for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom)]
+    assert not [m for m in imported if m.startswith("repro.kernels")]
+    leaves = {"conv", "ssm", "k", "v"}
+    assert not [n.value for n in ast.walk(tree)
+                if isinstance(n, ast.Constant) and n.value in leaves]
+    assert not [n.attr for n in ast.walk(tree) if isinstance(n, ast.Attribute)
+                and n.attr in ("window", "is_attention_free")]
 
 
 def test_decode_kernel_refuses_an_undividable_cache():
@@ -256,9 +279,9 @@ def _family(name):
     ("deepseek-moe-16b", 12)])
 def test_tokens_equal_served_alone(name, lanes):
     """Each request's greedy tokens equal those of the same request
-    served alone: joins mid-decode, a pool that shrinks as the queue
-    drains (``adaptive_lanes``), and, for the routed MoE, more tokens a
-    step than its default expert capacity."""
+    served alone: joins mid-decode, lanes left empty as the queue drains,
+    and, for the routed MoE, more tokens a step than its default expert
+    capacity."""
     model = _family(name)
     params = model.init(jax.random.PRNGKey(1))
     vocab = model.cfg.vocab_size
@@ -269,10 +292,7 @@ def test_tokens_equal_served_alone(name, lanes):
     max_news = [int(m) for m in rng.integers(2, 9, n_req)]
     mk = lambda: [Request(id=i, prompt=p, max_new=m)
                   for i, (p, m) in enumerate(zip(prompts, max_news))]
-    packed = BatchServer(model, params, batch_lanes=lanes, max_len=24,
-                         adaptive_lanes=True)
-    out = packed.run(mk())
-    assert packed.stats.resizes >= 1
+    out = BatchServer(model, params, batch_lanes=lanes, max_len=24).run(mk())
     solo = BatchServer(model, params, batch_lanes=1, max_len=24)
     for r in mk():
         assert out[r.id] == solo.run([r])[r.id], r.id

@@ -19,7 +19,7 @@ import pytest
 
 from repro import configs
 from repro.configs.base import ModelConfig, SSMConfig
-from repro.launch.serve import BatchServer, Request, cache_bytes
+from repro.launch.serve import BatchServer, Request
 from repro.models import build_model, ssm
 from repro.models.transformer import hybrid_layout
 
@@ -169,12 +169,11 @@ def test_served_logits_match_the_reference():
 
 def test_pool_counts_its_recurrent_state():
     model = _model()
-    shapes = jax.eval_shape(lambda: model.make_cache(3, 24))
-    total, state = cache_bytes(shapes)
+    total, state = model.pool_bytes(3, 24)
     assert state == 3 * ref.state_bytes(SMALL)
     assert total - state == 3 * 2 * (2 * 4 * 24 * 128 + 4 + 4 * 24)
     dense = build_model(configs.get("stablelm-1.6b").reduced())
-    assert cache_bytes(jax.eval_shape(lambda: dense.make_cache(2, 8)))[1] == 0
+    assert dense.pool_bytes(2, 8)[1] == 0
 
 
 def _to_torch(params, m, model):
